@@ -1315,6 +1315,9 @@ def main() -> None:
         "PATH-stem.<bench>_<engine>.json (serving compare modes)",
     )
     args = parser.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     global _TRACE_OUT
     _TRACE_OUT = args.trace_out
     if args.compare_storage:

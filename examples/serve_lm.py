@@ -53,6 +53,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_smoke_config, with_overrides
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.obs import Tracer, export_perfetto
 from repro.serving import (
@@ -135,6 +136,7 @@ def main():
                     help="trace the run and print the last N event "
                          "signatures")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     if args.impl:
@@ -224,7 +226,8 @@ def main():
     total_tokens = sum(len(r.out_tokens) for r in reqs)
     print(f"\n{sum(r.done for r in reqs)}/{len(reqs)} requests finished, "
           f"{total_tokens} tokens in {ticks} engine ticks ({dt:.1f}s, "
-          f"{total_tokens / max(dt, 1e-9):.1f} tok/s on CPU)")
+          f"{total_tokens / max(dt, 1e-9):.1f} tok/s on "
+          f"{jax.devices()[0].platform}, compiles included)")
     print(f"kv cache: {engine.kv_cache_nbytes() / 2**20:.2f} MiB "
           f"(impl={cfg.attention.impl}, storage={cfg.attention.spike_storage}, "
           f"backend={cfg.attention.backend})")

@@ -32,6 +32,7 @@ from .base import (
     is_paged_cache,
     next_pow2,
     paged_extent,
+    record_backends,
     register_backend,
     resolve_backend,
     resolve_backend_name,
@@ -67,6 +68,7 @@ __all__ = [
     "get_backend",
     "is_paged_cache",
     "paged_extent",
+    "record_backends",
     "register_backend",
     "resolve_backend",
     "resolve_backend_name",

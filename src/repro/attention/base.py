@@ -39,8 +39,10 @@ vLLM-style freedom (row migration, extent-bounded gathers, prefix sharing).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
-from typing import Optional, Protocol, runtime_checkable
+from typing import Iterator, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +64,7 @@ __all__ = [
     "available_backends",
     "resolve_backend_name",
     "resolve_backend",
+    "record_backends",
     "derive_request_seeds",
     "fold_layer_seeds",
     "derive_step_row_seeds",
@@ -247,6 +250,25 @@ def resolve_backend_name(
     return "ssa-fused"
 
 
+_RESOLVED: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
+    "repro_resolved_backends", default=None
+)
+
+
+@contextlib.contextmanager
+def record_backends() -> Iterator[dict[str, set[str]]]:
+    """Collect ``{mode: {backend name}}`` for every attention call traced
+    inside the block — what the compiled programs actually dispatch to.
+    Calls served from an already-compiled program are not traced again and
+    so are not recorded."""
+    rec: dict[str, set[str]] = {}
+    token = _RESOLVED.set(rec)
+    try:
+        yield rec
+    finally:
+        _RESOLVED.reset(token)
+
+
 def resolve_backend(
     a: AttentionConfig, mode: str, platform: Optional[str] = None
 ) -> AttentionBackend:
@@ -257,6 +279,9 @@ def resolve_backend(
             f"backend {name!r} does not support (impl={a.impl!r}, "
             f"mode={mode!r}, spike_storage={a.spike_storage!r})"
         )
+    rec = _RESOLVED.get()
+    if rec is not None:
+        rec.setdefault(mode, set()).add(name)
     return backend
 
 
@@ -303,14 +328,18 @@ def fold_layer_seeds(seeds: jax.Array, layer_index) -> jax.Array:
     return mix32(seeds.astype(jnp.uint32) ^ mix32(li * _LAYER_SALT + 1))
 
 
-def derive_step_row_seeds(seeds: jax.Array, t_steps: int, heads: int) -> jax.Array:
+def derive_step_row_seeds(
+    seeds: jax.Array, t_steps: int, heads: int, head0=0
+) -> jax.Array:
     """(B,) layer seeds -> (T, B*heads) uint32 stream seeds, fold_heads order.
 
     One independent counter-RNG stream per (sequence, head, time step).  The
     single place this expansion lives: all SSA backends call it, which is
     what keeps xla / fused / fused-packed bit-identical for the same seeds.
+    ``head0`` (may be traced) is the global index of the first of ``heads``
+    heads — a head shard derives exactly its heads' streams.
     """
-    h = jnp.arange(heads, dtype=jnp.uint32)
+    h = jnp.asarray(head0).astype(jnp.uint32) + jnp.arange(heads, dtype=jnp.uint32)
     t = jnp.arange(t_steps, dtype=jnp.uint32)
     s = mix32(seeds.astype(jnp.uint32)[:, None] + h[None, :] * _HEAD_SALT)
     s = mix32(s[None] + t[:, None, None] * _STEP_SALT)        # (T, B, H)

@@ -28,7 +28,7 @@ from .base import (
     fold_heads,
     register_backend,
 )
-from .spiking import folded_positions, rate_decode
+from .spiking import folded_positions, head_sharded, rate_decode
 
 __all__ = ["SdsaFusedPackedBackend"]
 
@@ -41,7 +41,8 @@ class SdsaFusedPackedBackend:
             a.impl == "sdsa" and a.spike_storage == "packed" and mode == "decode"
         )
 
-    def apply(self, inv: AttentionInvocation) -> jnp.ndarray:
+    @head_sharded
+    def apply(self, inv: AttentionInvocation, head0) -> jnp.ndarray:
         from repro.bitpack import pack_spikes
 
         if inv.packed_k is None or inv.packed_v is None:
@@ -61,7 +62,7 @@ class SdsaFusedPackedBackend:
         t_steps = qw.shape[0]
         b, h = inv.q.shape[0], inv.q.shape[2]
         seeds = inv.seeds if inv.seeds is not None else jnp.zeros(b, jnp.uint32)
-        step_seeds = derive_step_row_seeds(seeds, t_steps, h)
+        step_seeds = derive_step_row_seeds(seeds, t_steps, h, head0)
         q_pos, kv_pos = folded_positions(inv)
         interpret = default_interpret()
         outs = [

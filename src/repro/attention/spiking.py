@@ -9,12 +9,80 @@ for K/V; it keeps the words packed all the way to VMEM).
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .base import AttentionInvocation, fold_heads
 
-__all__ = ["folded_spike_trains", "folded_positions", "rate_decode"]
+__all__ = [
+    "folded_spike_trains",
+    "folded_positions",
+    "head_sharded",
+    "rate_decode",
+]
+
+# head axis of every per-head AttentionInvocation field
+_HEAD_AXIS = {
+    "q": 2, "k": 2, "v": 2,
+    "spike_q": 3, "spike_k": 3, "spike_v": 3,
+    "packed_k": 3, "packed_v": 3,
+}
+# per-sequence fields: identical on every head shard
+_ROW_FIELDS = ("seeds", "q_positions", "kv_positions")
+
+
+def head_sharded(apply_local):
+    """Decorator for the Pallas backends' ``apply(self, inv, head0)``.
+
+    XLA cannot partition a Mosaic kernel, so under an active sharding mesh
+    (``distributed.sharding.current_rules``) the call runs inside
+    ``shard_map``: one slice of heads per ``model`` shard when query and kv
+    head counts divide it, else the whole call on every shard.
+    ``apply_local`` gets the global index of its first head (``head0``),
+    which keys the per-head RNG streams, so each shard samples exactly what
+    the unsharded call samples for its heads.
+    """
+
+    @functools.wraps(apply_local)
+    def apply(self, inv: AttentionInvocation) -> jax.Array:
+        from repro.distributed.sharding import current_rules
+
+        rules = current_rules()
+        if rules is None:
+            return apply_local(self, inv, 0)
+        m = rules.model
+        heads = inv.q.shape[2]
+        split = m > 1 and heads % m == 0 and inv.a.num_kv_heads % m == 0
+        arrays = {
+            name: getattr(inv, name)
+            for name in (*_HEAD_AXIS, *_ROW_FIELDS)
+            if getattr(inv, name) is not None
+        }
+
+        def spec(name, x):
+            if not split or name not in _HEAD_AXIS:
+                return P()
+            axes = [None] * x.ndim
+            axes[_HEAD_AXIS[name]] = "model"
+            return P(*axes)
+
+        def body(local):
+            head0 = jax.lax.axis_index("model") * (heads // m) if split else 0
+            return apply_local(self, dataclasses.replace(inv, **local), head0)
+
+        return jax.shard_map(
+            body,
+            mesh=rules.mesh,
+            in_specs=({n: spec(n, x) for n, x in arrays.items()},),
+            out_specs=P(None, None, "model", None) if split else P(),
+            check_vma=False,
+        )(arrays)
+
+    return apply
 
 
 def folded_positions(inv: AttentionInvocation):

@@ -21,7 +21,12 @@ from .base import (
     derive_step_row_seeds,
     register_backend,
 )
-from .spiking import folded_positions, folded_spike_trains, rate_decode
+from .spiking import (
+    folded_positions,
+    folded_spike_trains,
+    head_sharded,
+    rate_decode,
+)
 
 __all__ = ["SsaFusedBackend"]
 
@@ -32,12 +37,13 @@ class SsaFusedBackend:
     def supports(self, a, mode: str) -> bool:
         return a.impl == "ssa"
 
-    def apply(self, inv: AttentionInvocation) -> jnp.ndarray:
+    @head_sharded
+    def apply(self, inv: AttentionInvocation, head0) -> jnp.ndarray:
         qs, ks, vs = folded_spike_trains(inv)
         t_steps = qs.shape[0]
         b, h = inv.q.shape[0], inv.q.shape[2]
         seeds = inv.seeds if inv.seeds is not None else jnp.zeros(b, jnp.uint32)
-        step_seeds = derive_step_row_seeds(seeds, t_steps, h)
+        step_seeds = derive_step_row_seeds(seeds, t_steps, h, head0)
         q_pos, kv_pos = folded_positions(inv)
         interpret = default_interpret()
         outs = [

@@ -48,7 +48,10 @@ def uniform_from_counter(seed: jnp.ndarray, counter: jnp.ndarray) -> jnp.ndarray
     paper's 16-bit LFSR comparators, with margin.
     """
     h = mix32(counter.astype(jnp.uint32) + mix32(seed))
-    return (h >> np.uint32(8)).astype(jnp.float32) * np.float32(1.0 / (1 << 24))
+    # Mosaic has no uint32 -> f32 cast; the value is < 2^24, so the detour
+    # through int32 is exact.
+    r = (h >> np.uint32(8)).astype(jnp.int32)
+    return r.astype(jnp.float32) * np.float32(1.0 / (1 << 24))
 
 
 def unpack_words_to_lanes(words):
@@ -62,7 +65,7 @@ def unpack_words_to_lanes(words):
     rows, w = words.shape
     shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
     bits = (words[:, :, None] >> shifts) & np.uint32(1)
-    return bits.reshape(rows, w * 32).astype(jnp.float32)
+    return bits.reshape(rows, w * 32).astype(jnp.int32).astype(jnp.float32)
 
 
 def cdiv(a: int, b: int) -> int:

@@ -8,9 +8,9 @@ paper map onto MXU lanes while HBM only ever sees packed words.
 
 Grid: ``(num_m_tiles, num_n_tiles, num_w_tiles)`` with the word (reduction)
 axis innermost; an f32 VMEM scratch tile accumulates partial counts across
-word tiles.  ``block_w`` words of uint32 expand to ``block_w * 32`` f32
-lanes, so VMEM holds ``block_m x (block_w * 32)`` per operand tile —
-the default (128, 16) expands to (128, 512) f32, comfortably inside VMEM.
+word tiles.  Within a word tile the words expand ``CHUNK_W`` at a time, so
+VMEM holds at most ``block_m x (CHUNK_W * 32)`` f32 lanes per operand —
+(128, 512) f32, comfortably inside VMEM whatever the word tile.
 """
 from __future__ import annotations
 
@@ -24,6 +24,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import cdiv, unpack_words_to_lanes
 
 __all__ = ["build_popcount_matmul_pallas"]
+
+# Words unpacked per MXU contraction inside one word tile.
+CHUNK_W = 16
 
 
 def _popcount_matmul_kernel(
@@ -40,13 +43,18 @@ def _popcount_matmul_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = unpack_words_to_lanes(a_ref[...])   # (block_m, block_w * 32) 0/1 f32
-    b = unpack_words_to_lanes(b_ref[...])   # (block_n, block_w * 32)
     # 0/1 operands: dot == popcount of AND; f32 accumulation is exact for
-    # counts <= 2^24 (i.e. any realistic D_K / T product).
-    acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    # counts <= 2^24 (i.e. any realistic D_K / T product).  The word tile
+    # unpacks CHUNK_W words at a time so VMEM holds at most
+    # (block, CHUNK_W * 32) f32 lanes per operand.
+    block_w = a_ref.shape[1]
+    for c0 in range(0, block_w, CHUNK_W):
+        c1 = min(c0 + CHUNK_W, block_w)
+        a = unpack_words_to_lanes(a_ref[:, c0:c1])   # (block_m, (c1-c0)*32)
+        b = unpack_words_to_lanes(b_ref[:, c0:c1])
+        acc_ref[...] += jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
 
     @pl.when(iw == num_w_tiles - 1)
     def _finalize():

@@ -37,7 +37,7 @@ def popcount_matmul(
     *,
     block_m: int = 128,
     block_n: int = 128,
-    block_w: int = 16,
+    block_w: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """AND-popcount contraction on packed spike words.
@@ -71,6 +71,9 @@ def popcount_matmul(
 
     m, w = a_packed.shape
     n = b_packed.shape[0]
+    # Mosaic tiles the word (lane) axis only in multiples of 128 words; a
+    # width that fits one tile is taken whole (block == full array dim).
+    block_w = w if w <= block_w else cdiv(block_w, 128) * 128
     m_pad = cdiv(m, block_m) * block_m
     n_pad = cdiv(n, block_n) * block_n
     w_pad = cdiv(w, block_w) * block_w

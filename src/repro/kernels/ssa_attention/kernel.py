@@ -118,8 +118,7 @@ def _ssa_tile_body(
     kp_u = jnp.maximum(kp, 0).astype(jnp.uint32)
     idx_s = qp_u * POS_STRIDE_S + kp_u
     u_s = uniform_from_counter(seed_ref[b, 0] ^ SALT_S, idx_s)
-    s = jnp.where(valid, u_s * jnp.float32(d_k) < counts_s, False)
-    s = s.astype(jnp.float32)
+    s = (valid & (u_s * jnp.float32(d_k) < counts_s)).astype(jnp.float32)
 
     # ---- eq. 6 partials: acc += S-tile @ V-tile; vis += |valid| -----------
     acc_ref[...] += jax.lax.dot_general(

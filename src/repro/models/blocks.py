@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.attention import (
     AttentionInvocation,
@@ -45,9 +46,23 @@ PACKED_IMPLS = ("ssa", "sdsa")
 # ---------------------------------------------------------------------------
 
 
+def draw_normal(key, shape) -> jax.Array:
+    """``N(0, 1)`` f32 draw for a parameter initialiser.
+
+    The barrier keeps XLA from folding the caller's scale into the sampler's
+    own constants, so a jitted init draws exactly the values an eager one
+    does; callers scale by numpy f32 constants for the same reason.
+    """
+    return jax.lax.optimization_barrier(jax.random.normal(key, shape))
+
+
+def inv_sqrt(n: int) -> np.float32:
+    """``1 / sqrt(n)`` in f32, folded on the host (never as an XLA rsqrt)."""
+    return np.float32(1.0) / np.sqrt(np.float32(n))
+
+
 def dense_init(key, d_in: int, d_out: int, dtype=jnp.float32) -> jax.Array:
-    scale = 1.0 / jnp.sqrt(d_in)
-    return (jax.random.normal(key, (d_in, d_out)) * scale).astype(dtype)
+    return (draw_normal(key, (d_in, d_out)) * inv_sqrt(d_in)).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -537,16 +552,19 @@ def mlp_apply(p: dict, x: jax.Array, act: str) -> jax.Array:
 def moe_params(key, d_model: int, moe: MoEConfig, act: str, dtype=jnp.float32) -> dict:
     ks = jax.random.split(key, 5)
     e, f = moe.num_experts, moe.expert_ffn_dim
-    scale = 1.0 / jnp.sqrt(d_model)
 
     def ew(k, shape):
-        return (jax.random.normal(k, shape) * scale).astype(dtype)
+        return (draw_normal(k, shape) * inv_sqrt(d_model)).astype(dtype)
 
     p = {
         "router": dense_init(ks[0], d_model, e, jnp.float32),
         "wi": ew(ks[1], (e, d_model, f)),
         "wg": ew(ks[2], (e, d_model, f)),
-        "wo": (jax.random.normal(ks[3], (e, f, d_model)) / jnp.sqrt(f)).astype(dtype),
+        # divisor behind a barrier: XLA would turn x / c into x * (1 / c)
+        "wo": (
+            draw_normal(ks[3], (e, f, d_model))
+            / jax.lax.optimization_barrier(np.sqrt(np.float32(f)))
+        ).astype(dtype),
     }
     if moe.num_shared_experts:
         p["shared"] = mlp_params(ks[4], d_model, moe.shared_ffn_dim, act, dtype)

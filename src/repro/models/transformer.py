@@ -21,6 +21,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.attention import derive_request_seeds, fold_layer_seeds
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -30,6 +31,7 @@ from .blocks import (
     PACKED_IMPLS,
     attention_apply,
     attention_params,
+    draw_normal,
     mlp_apply,
     mlp_params,
     moe_apply,
@@ -52,6 +54,12 @@ def cross_entropy(logits: jax.Array, labels: jax.Array, mask: Optional[jax.Array
         nll = nll * mask
         return nll.sum() / jnp.maximum(mask.sum(), 1.0)
     return nll.mean()
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_init(cfg: ModelConfig):
+    """One compiled init per config, shared by every model built from it."""
+    return jax.jit(DecoderLM(cfg)._init)
 
 
 class DecoderLM:
@@ -91,12 +99,24 @@ class DecoderLM:
         return p
 
     def init(self, key) -> dict:
+        """Random parameters, built as one jitted program.
+
+        Layers are drawn one at a time (``lax.map``) and each f32 draw is
+        cast to ``cfg.dtype`` inside the program, so the only f32
+        temporaries are one layer's (or the embedding's) worth — a
+        full-width stack fits one chip.  The draws are bit-identical to
+        eager, vmapped initialisation (``blocks.draw_normal``).
+        """
+        return _jitted_init(self.cfg)(key)
+
+    def _init(self, key) -> dict:
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         k_embed, k_layers, k_head = jax.random.split(key, 3)
         params: dict[str, Any] = {
             "embed": (
-                jax.random.normal(k_embed, (cfg.vocab_size, cfg.d_model)) * 0.02
+                draw_normal(k_embed, (cfg.vocab_size, cfg.d_model))
+                * np.float32(0.02)
             ).astype(dtype),
             "final_norm": norm_params(cfg.d_model, cfg.norm),
         }
@@ -104,12 +124,12 @@ class DecoderLM:
         slots = []
         for s in range(len(self.pattern)):
             keys = jax.random.split(jax.random.fold_in(k_layers, s), self.steps)
-            stacked = jax.vmap(self._layer_params)(keys)
-            slots.append(stacked)
+            slots.append(jax.lax.map(self._layer_params, keys))
         params["slots"] = slots
         if not cfg.tie_embeddings:
             params["lm_head"] = (
-                jax.random.normal(k_head, (cfg.d_model, cfg.vocab_size)) * 0.02
+                draw_normal(k_head, (cfg.d_model, cfg.vocab_size))
+                * np.float32(0.02)
             ).astype(dtype)
         return params
 

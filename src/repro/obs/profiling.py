@@ -12,37 +12,13 @@ Two flavours, matching how JAX attributes time:
   span that shows up on the profiler's Python track.  The serving engine
   opens one around prefill / decode dispatch only when a tracer is
   attached, keeping the untraced tick free of per-tick instrumentation.
-
-Both degrade to a no-op context if the running JAX build lacks the API,
-so importing this module can never be the thing that breaks a host.
 """
 from __future__ import annotations
 
-import contextlib
+import jax
+from jax.profiler import TraceAnnotation
 
 __all__ = ["annotate", "trace_scope"]
 
-
-@contextlib.contextmanager
-def _null():
-    yield
-
-
-def trace_scope(name: str):
-    """``jax.named_scope`` if available, else a no-op context."""
-    try:
-        import jax
-
-        return jax.named_scope(name)
-    except Exception:  # pragma: no cover - jax-version fallback
-        return _null()
-
-
-def annotate(name: str, **kwargs):
-    """``jax.profiler.TraceAnnotation`` if available, else a no-op."""
-    try:
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(name, **kwargs)
-    except Exception:  # pragma: no cover - jax-version fallback
-        return _null()
+trace_scope = jax.named_scope
+annotate = TraceAnnotation

@@ -2264,10 +2264,7 @@ class ServingEngine:
         """Number of distinct compiled prefill signatures this engine has
         triggered (== distinct prompt-length buckets when bucketing is on)."""
         if self._prefill is not None:
-            try:
-                return int(self._prefill._cache_size())
-            except Exception:  # pragma: no cover - jax-version fallback
-                pass
+            return int(self._prefill._cache_size())
         return len(self._prefill_buckets)
 
     # ------------------------------------------------------------------

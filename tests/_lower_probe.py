@@ -17,6 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ParallelConfig, ShapeConfig, TrainConfig, get_smoke_config
 from repro.distributed.sharding import ShardingRules
+from repro.launch.mesh import make_mesh
 from repro.distributed.steps import (
     batch_pspecs,
     build_train_step,
@@ -34,7 +35,7 @@ def probe(arch: str, impl: str | None = None):
         cfg = dataclasses.replace(
             cfg, attention=dataclasses.replace(cfg.attention, impl=impl)
         )
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = ShardingRules(mesh, batch_shardable=True, seq_parallel=True)
     parallel = ParallelConfig(remat="dots")
     model = build_model(cfg)

@@ -3,7 +3,9 @@
 Runs in its own interpreter so the parent pytest process can force an
 8-device host mesh via XLA_FLAGS without contaminating its own jax
 backend.  Asserts that tensor-parallel (2- and 4-shard) and 2-replica
-engines reproduce the committed golden token streams bit-for-bit.
+engines reproduce the committed golden token streams bit-for-bit; with
+``--vs-unsharded``, that 2- and 4-shard engines reproduce the unsharded
+engine's streams, for the XLA and the fused (Pallas) backends.
 """
 import dataclasses
 import json
@@ -39,26 +41,49 @@ def streams(engine):
     return [list(map(int, r.out_tokens)) for r in reqs]
 
 
+def build(arch, storage, layout, backend="auto"):
+    cfg = get_smoke_config(arch)
+    cfg = dataclasses.replace(
+        cfg,
+        attention=dataclasses.replace(
+            cfg.attention, impl="ssa", spike_storage=storage,
+            cache_layout=layout, backend=backend,
+        ),
+    )
+    model = build_model(cfg)
+    kw = dict(num_slots=2, max_seq=32)
+    if layout == "paged":
+        kw["page_size"] = 8
+    return model, model.init(jax.random.PRNGKey(0)), kw
+
+
+def vs_unsharded():
+    """TP engines against the unsharded engine in this same process, for
+    the XLA backends and the Pallas kernels (run per head shard)."""
+    for name, arch, storage, layout in CASES:
+        # the windowed gemma2 case adds no kernel path: XLA only there
+        backends = ("xla",) if arch == "gemma2_9b" else ("xla", "fused")
+        for backend in backends:
+            model, params, kw = build(arch, storage, layout, backend)
+            want = streams(ServingEngine(model, params, **kw))
+            for shards in (2, 4):
+                got = streams(ServingEngine(model, params,
+                                            mesh_shards=shards, **kw))
+                assert got == want, (name, backend, f"tp{shards}", got, want)
+                print(name, backend, f"tp{shards} ok", flush=True)
+    print("SHARDED_PROBE_OK")
+
+
 def main():
     assert len(jax.devices()) >= 4, (
         f"probe needs >= 4 devices, got {len(jax.devices())}"
     )
+    if sys.argv[1:] == ["--vs-unsharded"]:
+        return vs_unsharded()
     for name, arch, storage, layout in CASES:
         with open(GOLDEN / f"{name}.json") as f:
             want = json.load(f)["streams"]
-        cfg = get_smoke_config(arch)
-        cfg = dataclasses.replace(
-            cfg,
-            attention=dataclasses.replace(
-                cfg.attention, impl="ssa", spike_storage=storage,
-                cache_layout=layout,
-            ),
-        )
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
-        kw = dict(num_slots=2, max_seq=32)
-        if layout == "paged":
-            kw["page_size"] = 8
+        model, params, kw = build(arch, storage, layout)
         for shards in (2, 4):
             got = streams(ServingEngine(model, params,
                                         mesh_shards=shards, **kw))

@@ -157,3 +157,27 @@ def test_ssa_mode_in_lm_arch():
     assert np.isfinite(float(loss))
     gnorm = sum(float(jnp.abs(g).sum()) for g in jax.tree.leaves(grads))
     assert gnorm > 0
+
+
+@pytest.mark.parametrize(
+    "arch,dtype",
+    [("codeqwen15_7b", "float32"), ("codeqwen15_7b", "bfloat16"),
+     ("mixtral_8x7b", "float32")],
+)
+def test_decoder_init_jit_matches_eager(arch, dtype):
+    """``DecoderLM.init`` compiles one program; its draws must be the bits
+    an eager, op-by-op initialisation draws (XLA may not fold the init
+    scales into the sampler's constants)."""
+    from repro.configs import with_overrides
+
+    cfg = with_overrides(get_smoke_config(arch), dtype=dtype)
+    model = build_model(cfg)
+    key = jax.random.PRNGKey(3)
+    jitted = model.init(key)
+    with jax.disable_jit():
+        eager = model._init(key)
+    assert jax.tree.structure(jitted) == jax.tree.structure(eager)
+    assert jitted["embed"].dtype == jnp.dtype(dtype)
+    for a, b in zip(jax.tree.leaves(jitted), jax.tree.leaves(eager)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

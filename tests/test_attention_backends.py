@@ -99,6 +99,46 @@ def test_fused_backend_requires_ssa():
         build_model(with_overrides(cfg, attention__backend="nope"))
 
 
+def test_record_backends_sees_what_traced_calls_resolve():
+    """``record_backends`` collects the backend every traced attention
+    call resolved, by mode; outside the block nothing is recorded."""
+    from repro.attention import record_backends
+
+    cfg = _ssa_cfg(backend="fused", storage="packed")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.ones((1, 4), jnp.int32),
+             "positions": jnp.arange(4, dtype=jnp.int32)[None]}
+    with record_backends() as rec:
+        _, cache = model.prefill(params, batch, model.init_cache(1, 8))
+        model.decode_step(
+            params, {"tokens": jnp.ones((1, 1), jnp.int32),
+                     "positions": jnp.full((1, 1), 4, jnp.int32)},
+            cache, jnp.full((1,), 4, jnp.int32),
+        )
+    assert rec == {"prefill": {"ssa-fused"}, "decode": {"ssa-fused-packed"}}
+    with record_backends() as rec:
+        pass
+    model.prefill(params, batch, model.init_cache(1, 8))
+    assert rec == {}
+
+
+def test_step_row_seeds_of_a_head_slice_match_the_full_expansion():
+    """A head shard (``head0``) derives exactly its heads' streams — what
+    lets ``head_sharded`` run the Pallas backends per shard bit-exactly."""
+    from repro.attention import derive_step_row_seeds
+
+    seeds = jnp.asarray([17, 23, 99], jnp.uint32)
+    t, h, shards = 3, 8, 4
+    full = derive_step_row_seeds(seeds, t, h).reshape(t, 3, h)
+    for i in range(shards):
+        part = derive_step_row_seeds(seeds, t, h // shards, i * h // shards)
+        np.testing.assert_array_equal(
+            np.asarray(part).reshape(t, 3, h // shards),
+            np.asarray(full[:, :, i * h // shards:(i + 1) * h // shards]),
+        )
+
+
 # ---------------------------------------------------------------------------
 # backend parity at model level (attention_apply orchestration included)
 # ---------------------------------------------------------------------------

@@ -353,8 +353,7 @@ def test_replica_invariants_hold_under_random_schedules(data):
 # ---------------------------------------------------------------------------
 # full TP matrix from a single-device pytest process (subprocess pattern)
 # ---------------------------------------------------------------------------
-@pytest.mark.slow
-def test_sharded_identity_subprocess():
+def _run_sharded_probe(*args):
     probe = Path(__file__).parent / "_sharded_probe.py"
     env = {
         **os.environ,
@@ -363,8 +362,20 @@ def test_sharded_identity_subprocess():
         "PYTHONPATH": str(Path(__file__).parents[1] / "src"),
     }
     r = subprocess.run(
-        [sys.executable, str(probe)],
+        [sys.executable, str(probe), *args],
         env=env, capture_output=True, text=True, timeout=900,
     )
     assert r.returncode == 0, r.stderr[-3000:]
     assert "SHARDED_PROBE_OK" in r.stdout
+
+
+@pytest.mark.slow
+def test_sharded_identity_subprocess():
+    _run_sharded_probe()
+
+
+@pytest.mark.slow
+def test_sharded_matches_unsharded_subprocess():
+    """2- and 4-shard engines give the unsharded engine's streams, with the
+    Pallas kernels (interpret mode here) run per head shard."""
+    _run_sharded_probe("--vs-unsharded")

@@ -10,8 +10,9 @@ Two flavours, matching how JAX attributes time:
   fusions.
 * :func:`annotate` — ``jax.profiler.TraceAnnotation``: a *host-side*
   span that shows up on the profiler's Python track.  The serving engine
-  opens one around prefill / decode dispatch only when a tracer is
-  attached, keeping the untraced tick free of per-tick instrumentation.
+  opens them (tick phases, admission, token fetch, program launches) only
+  when a tracer is attached, keeping the untraced tick free of per-tick
+  instrumentation.
 """
 from __future__ import annotations
 

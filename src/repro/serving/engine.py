@@ -309,10 +309,16 @@ _copy_jit = jax.jit(_copy_page)
 def _model_jit(model, key: str, make):
     """Memoise jitted model entry points on the model instance itself, so
     engines over the same model (tests build many) share compiled code
-    instead of re-tracing per engine."""
+    instead of re-tracing per engine.
+
+    The jitted function is named after its key (``serve_decode_seeded``,
+    ``serve_chunk_tp4`` for ``chunk@tp4``), so device profiles show the
+    program as ``jit_serve_<key>`` rather than ``jit__lambda``."""
     cache = model.__dict__.setdefault("_serving_jit_cache", {})
     if key not in cache:
-        cache[key] = jax.jit(make())
+        fn = make()
+        fn.__name__ = fn.__qualname__ = "serve_" + key.replace("@", "_")
+        cache[key] = jax.jit(fn)
     return cache[key]
 
 
@@ -388,20 +394,25 @@ _NULL_CTX = _NullCtx()
 
 
 class _PhaseTimer:
-    """Times one named tick phase; emits a histogram sample + phase event."""
+    """Times one named tick phase; emits a histogram sample + phase event
+    and opens the profiler span ``repro/<name>`` around the phase, so the
+    device trace sees the tick's structure on its own clock."""
 
-    __slots__ = ("eng", "name", "t0")
+    __slots__ = ("eng", "name", "span", "t0")
 
     def __init__(self, eng, name):
         self.eng = eng
         self.name = name
+        self.span = annotate(f"repro/{name}")
 
     def __enter__(self):
+        self.span.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
+        self.span.__exit__(*exc)
         self.eng.metrics.observe(f"phase_{self.name}_s", dur)
         self.eng._trace("phase", phase=self.name, dur_s=dur)
         return False
@@ -824,6 +835,10 @@ class ServingEngine:
         """Timed named tick phase when traced; a shared no-op otherwise."""
         return _NULL_CTX if self.tracer is None else _PhaseTimer(self, name)
 
+    def _span(self, name: str):
+        """Profiler span ``name`` when traced; the shared no-op otherwise."""
+        return _NULL_CTX if self.tracer is None else annotate(name)
+
     def _compile_event(self, fn: str, signature):
         """A jit entry point is about to see a new signature."""
         self.metrics.inc("compile_events")
@@ -929,22 +944,17 @@ class ServingEngine:
                 row_cache,
                 jnp.asarray(p - 1, jnp.int32),
             )
-            ctx = (annotate("repro/prefill_dispatch")
-                   if self.tracer is not None else _NULL_CTX)
-            with ctx:
-                if self._prefill_seeded:
-                    logits, row_cache = self._prefill(*args, _dev(seeds))
-                else:
-                    logits, row_cache = self._prefill(*args)
+            if self._prefill_seeded:
+                args += (_dev(seeds),)
+            with self._span("repro/prefill_dispatch"):
+                logits, row_cache = self._prefill(*args)
             if pb != p:
                 row_cache = self._reset_pad_rows(row_cache, p)
         else:
             tokens = _dev(np.asarray(req.prompt, np.int32)[None])
             positions = _dev(np.arange(p, dtype=np.int32)[None])
             kwargs = {"seeds": _dev(seeds)} if self._prefill_seeded else {}
-            ctx = (annotate("repro/prefill_dispatch")
-                   if self.tracer is not None else _NULL_CTX)
-            with ctx:
+            with self._span("repro/prefill_dispatch"):
                 logits, row_cache = self.model.prefill(
                     self.params,
                     {"tokens": tokens, "positions": positions},
@@ -963,7 +973,8 @@ class ServingEngine:
         now = time.perf_counter()
         m.observe("ttft_wall_s", now - self._submit_wall.pop(id(req), now))
         self.key, sub = jax.random.split(self.key)
-        nxt = int(self.sampler(sub, logits[0, -1]))
+        with self._span("repro/fetch_tokens"):
+            nxt = int(self.sampler(sub, logits[0, -1]))
         req.out_tokens.append(nxt)
         m.inc("tokens_sampled")
         if len(req.out_tokens) >= req.max_new_tokens:
@@ -1222,17 +1233,18 @@ class ServingEngine:
         if (sb, width) not in self._chunk_signatures:
             self._chunk_signatures.add((sb, width))
             self._compile_event("prefill_chunk", [sb, width])
-        ctx = (annotate("repro/prefill_chunk_dispatch")
-               if self.tracer is not None else _NULL_CTX)
-        with ctx:
-            logits, self.cache = self._chunk(
-                self.params,
-                {"tokens": _dev(tokens), "positions": _dev(positions)},
-                cache_view,
-                _dev(np.full((1,), c0, np.int32)),
-                _dev(np.asarray([req.seed], np.uint32)),
-                jnp.asarray(s - 1, jnp.int32),
-            )
+        args = (
+            self.params,
+            {"tokens": _dev(tokens), "positions": _dev(positions)},
+            cache_view,
+            _dev(np.full((1,), c0, np.int32)),
+            _dev(np.asarray([req.seed], np.uint32)),
+            jnp.asarray(s - 1, jnp.int32),
+        )
+        # the span holds the program launch alone: operand transfers and
+        # scalar conversions happen before it
+        with self._span("repro/prefill_chunk_dispatch"):
+            logits, self.cache = self._chunk(*args)
         self.metrics.inc("prefill_chunks_run")
         self._trace("prefill_chunk", uid=req.uid, c0=c0, c1=c1,
                     bucket=sb, width=width)
@@ -1511,18 +1523,12 @@ class ServingEngine:
             "tokens": _dev(tokens),
             "positions": _dev(positions),
         }
-        idx = _dev(self.slot_pos)                    # per-row write offsets
-        ctx = (annotate("repro/decode_dispatch")
-               if self.tracer is not None else _NULL_CTX)
-        with ctx:
-            if self._seeded:
-                logits, self.cache = self._decode(
-                    self.params, batch, self.cache, idx, _dev(self.slot_seeds)
-                )
-            else:
-                logits, self.cache = self._decode(
-                    self.params, batch, self.cache, idx
-                )
+        args = (self.params, batch, self.cache,
+                _dev(self.slot_pos))                 # per-row write offsets
+        if self._seeded:
+            args += (_dev(self.slot_seeds),)
+        with self._span("repro/decode_dispatch"):
+            logits, self.cache = self._decode(*args)
         return logits
 
     def _replay(self, slot: int, req: Request):
@@ -1741,14 +1747,17 @@ class ServingEngine:
         else:
             self._draft_params = self.params
         self.spec_k = int(draft.k)
+        # own keys, so draft programs are named apart from the target's
+        # (``serve_draft_decode_seeded``, ``serve_draft_chunk``) even when
+        # the draft is the target model itself
         self._draft_decode = self._jit_model(
-            dmodel, "decode_seeded",
+            dmodel, "draft_decode_seeded",
             lambda: lambda p, batch, cache, idx, seeds: dmodel.decode_step(
                 p, batch, cache, idx, seeds=seeds
             ),
         )
         self._draft_chunk = self._jit_model(
-            dmodel, "chunk",
+            dmodel, "draft_chunk",
             lambda: lambda p, batch, cache, idx, seeds, last:
                 dmodel.decode_step(
                     p, batch, cache, idx, seeds=seeds, logits_at=last
@@ -1917,14 +1926,14 @@ class ServingEngine:
         if sig not in self._draft_chunk_signatures:
             self._draft_chunk_signatures.add(sig)
             self._compile_event("draft_catchup", sig)
-        batch = {"tokens": _dev(tokens), "positions": _dev(positions)}
-        ctx = (annotate("repro/draft_dispatch")
-               if self.tracer is not None else _NULL_CTX)
-        with ctx:
-            logits, self._draft_cache = self._draft_chunk(
-                self._draft_params, batch, self._draft_cache, _dev(idx),
-                _dev(self.slot_seeds), jnp.asarray(0, jnp.int32),
-            )
+        args = (
+            self._draft_params,
+            {"tokens": _dev(tokens), "positions": _dev(positions)},
+            self._draft_cache, _dev(idx), _dev(self.slot_seeds),
+            jnp.asarray(0, jnp.int32),
+        )
+        with self._span("repro/draft_dispatch"):
+            logits, self._draft_cache = self._draft_chunk(*args)
         del logits
         self.metrics.inc("draft_dispatches")
         self._draft_pos[slot] = p0
@@ -1976,14 +1985,13 @@ class ServingEngine:
                     idx[s] = p0_s + int(k_row[s])   # first stale offset
             if self.paged:
                 self._sync_draft_tables(int(idx.max()) + 1)
-            batch = {"tokens": _dev(tokens), "positions": _dev(positions)}
-            ctx = (annotate("repro/draft_dispatch")
-                   if self.tracer is not None else _NULL_CTX)
-            with ctx:
-                logits, self._draft_cache = self._draft_decode(
-                    self._draft_params, batch, self._draft_cache,
-                    _dev(idx), _dev(self.slot_seeds),
-                )
+            args = (
+                self._draft_params,
+                {"tokens": _dev(tokens), "positions": _dev(positions)},
+                self._draft_cache, _dev(idx), _dev(self.slot_seeds),
+            )
+            with self._span("repro/draft_dispatch"):
+                logits, self._draft_cache = self._draft_decode(*args)
             self.metrics.inc("draft_dispatches")
             nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             for s in rows:
@@ -2059,14 +2067,13 @@ class ServingEngine:
                 self._spec_widths.add(sig)
                 self._compile_event("verify", sig)
         self._trace("verify", width=width, active=len(self.active))
-        batch = {"tokens": _dev(tokens), "positions": _dev(positions)}
-        ctx = (annotate("repro/verify_dispatch")
-               if self.tracer is not None else _NULL_CTX)
-        with ctx:
-            logits, self.cache = self._decode(
-                self.params, batch, self.cache, _dev(idx),
-                _dev(self.slot_seeds),
-            )
+        args = (
+            self.params,
+            {"tokens": _dev(tokens), "positions": _dev(positions)},
+            self.cache, _dev(idx), _dev(self.slot_seeds),
+        )
+        with self._span("repro/verify_dispatch"):
+            logits, self.cache = self._decode(*args)
         self.metrics.inc("verify_dispatches")
         return logits
 
@@ -2109,12 +2116,13 @@ class ServingEngine:
         (distribution-exact), with a different key schedule."""
         m = self.metrics
         self.key, sub = jax.random.split(self.key)
-        cand = np.stack(
-            [np.asarray(self.sampler(jax.random.fold_in(sub, j),
-                                     logits[:, j]))
-             for j in range(width)],
-            axis=1,
-        )
+        with self._span("repro/fetch_tokens"):
+            cand = np.stack(
+                [np.asarray(self.sampler(jax.random.fold_in(sub, j),
+                                         logits[:, j]))
+                 for j in range(width)],
+                axis=1,
+            )
         now = time.perf_counter()
         tick = self._ticks.value
         finished: list[Request] = []
@@ -2281,7 +2289,8 @@ class ServingEngine:
         with self._phase("schedule"):
             if self.paged:
                 self._resume_preempted()
-            self._admit()
+            with self._span("repro/admit"):
+                self._admit()
             if self.active and self.paged:
                 self._grow_pages()
                 if self._draft_model is None:
@@ -2329,7 +2338,8 @@ class ServingEngine:
         with self._phase("sample"):
             self._ticks.inc()
             self.key, sub = jax.random.split(self.key)
-            nxt = np.asarray(self.sampler(sub, logits[:, -1]))
+            with self._span("repro/fetch_tokens"):
+                nxt = np.asarray(self.sampler(sub, logits[:, -1]))
             finished = self._commit(nxt)
         return finished0 + finished
 

@@ -358,6 +358,113 @@ def test_legacy_counter_properties_mirror_registry():
 
 
 # ---------------------------------------------------------------------------
+# profiler spans and program names (what a device trace reads)
+# ---------------------------------------------------------------------------
+def _profiled_host_events(tmp_path, tracer):
+    """Serve two chunked admissions and their decode ticks under
+    ``jax.profiler.trace``; returns every host event as
+    ``(name, start_ns, end_ns, line)``."""
+    from jax.profiler import ProfileData
+
+    cfg = _paged_cfg()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(
+        model, params, num_slots=2, max_seq=32, page_size=8,
+        num_pages=NUM_RESERVED_PAGES + 8, tracer=tracer,
+    )
+    rng = np.random.default_rng(5)
+
+    def burst(uid0):
+        # 12-token prompts: two chunks of one 8-row page each admission
+        return [Request(uid=uid0 + i, max_new_tokens=3,
+                        prompt=rng.integers(0, cfg.vocab_size, 12)
+                        .astype(np.int32)) for i in range(2)]
+
+    _drive(eng, burst(0), arrivals=[0, 0])      # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        _drive(eng, burst(2), arrivals=[0, 0])
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            (plane.name, i)) for e in line.events]
+    return events
+
+
+def _within(events, inner, outers):
+    """(how many ``inner`` spans lie inside an ``outers`` span on the same
+    thread, how many ``inner`` spans there are)."""
+    ins = [e for e in events if e[0] == inner]
+    outs = [e for e in events if e[0] in outers]
+    n = sum(any(o[3] == line and o[1] <= a and b <= o[2] for o in outs)
+            for _, a, b, line in ins)
+    return n, len(ins)
+
+
+def test_tick_phases_are_profiler_spans(tmp_path):
+    """A traced engine labels each tick phase on the profiler's clock, with
+    every program launch innermost in its dispatch span.  Two admissions
+    of two chunks each, then two decode ticks."""
+    events = _profiled_host_events(tmp_path, Tracer())
+    names = {e[0] for e in events}
+    assert {"repro/schedule", "repro/admit", "repro/host_stage",
+            "repro/dispatch", "repro/sample", "repro/fetch_tokens"} <= names
+    assert _within(events, "repro/decode_dispatch", {"repro/dispatch"}) == (2, 2)
+    assert _within(events, "repro/prefill_chunk_dispatch",
+                   {"repro/admit"}) == (4, 4)
+    assert _within(events, "repro/admit", {"repro/schedule"}) == (2, 2)
+    # a tick's tokens are fetched in sample, an admission's first token
+    # inside admit
+    assert _within(events, "repro/fetch_tokens", {"repro/sample"}) == (2, 4)
+    assert _within(events, "repro/fetch_tokens",
+                   {"repro/sample", "repro/admit"}) == (4, 4)
+
+
+def test_untraced_engine_opens_no_profiler_span(tmp_path):
+    """With no tracer the tick opens no ``repro/`` span (and the trace
+    still records the engine's named programs being launched)."""
+    events = _profiled_host_events(tmp_path, None)
+    names = {e[0] for e in events}
+    assert not [n for n in names if n.startswith("repro/")]
+    assert "PjitFunction(serve_decode_seeded)" in names
+
+
+def test_engine_programs_lower_to_named_modules():
+    """Decode and chunk programs are named after their jit keys; the
+    draft's are named apart from the target's."""
+    from repro.serving import DraftConfig
+
+    cfg = _paged_cfg()
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(model, params, num_slots=2, max_seq=32, page_size=8,
+                        num_pages=NUM_RESERVED_PAGES + 8)
+    seen = {}
+    for attr in ("_decode", "_chunk"):
+        def spy(*args, _fn=getattr(eng, attr), _attr=attr):
+            seen.setdefault(_attr, (_fn, jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)))
+            return _fn(*args)
+        setattr(eng, attr, spy)
+    _drive(eng, _burst(cfg, n=1), arrivals=[0])
+    for attr, module in (("_decode", "jit_serve_decode_seeded"),
+                         ("_chunk", "jit_serve_chunk")):
+        fn, args = seen[attr]
+        assert f"module @{module} " in fn.lower(*args).as_text()
+    spec = ServingEngine(model, params, num_slots=2, max_seq=32, page_size=8,
+                         num_pages=NUM_RESERVED_PAGES + 8,
+                         draft=DraftConfig(k=2))
+    assert spec._decode.__name__ == "serve_decode_seeded"
+    assert spec._draft_decode.__name__ == "serve_draft_decode_seeded"
+    assert spec._draft_chunk.__name__ == "serve_draft_chunk"
+
+
+# ---------------------------------------------------------------------------
 # golden event stream: pinned preempt/migrate/resume/share scenario
 # ---------------------------------------------------------------------------
 def test_golden_event_stream_paged_scheduler(golden):

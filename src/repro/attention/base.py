@@ -97,7 +97,9 @@ MODES = ("train", "prefill", "decode")
 
 # Default tile geometry for the fused kernels.  Since RNG contract v2 the
 # counter streams are independent of tiling (position-keyed), so these are
-# pure performance knobs — any block size samples the same spikes.
+# pure performance knobs — any block size samples the same spikes.  Packed
+# calls take their tiles from the shapes (a KV-head group's query rows,
+# capped at DEFAULT_BLOCK_Q; see kernels/ssa_attention/ops.py).
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
@@ -107,7 +109,8 @@ class AttentionInvocation:
     """Everything a backend may consume, prepared by the orchestration layer.
 
     Dense activations are post-RoPE; ``k``/``v`` stay at KV-head granularity
-    (``groups`` = query heads per kv head, the backend repeats as needed).
+    (``groups`` = query heads per kv head; the XLA backends repeat them,
+    the packed kernels tile each group's query heads against them).
     Spiking callers provide pre-encoded trains (``spike_*``, shape
     ``(T, B, S, H, hd)``) and/or packed uint32 bit-planes (``packed_*``,
     shape ``(B, S, T, H_kv, ceil(hd/32))`` — the packed KV-cache layout).

@@ -16,12 +16,20 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .base import AttentionInvocation, fold_heads
+from .base import (
+    DEFAULT_BLOCK_K,
+    DEFAULT_BLOCK_Q,
+    AttentionInvocation,
+    default_interpret,
+    derive_step_row_seeds,
+    fold_heads,
+)
 
 __all__ = [
     "folded_spike_trains",
     "folded_positions",
     "head_sharded",
+    "packed_fused_decode",
     "rate_decode",
 ]
 
@@ -85,21 +93,68 @@ def head_sharded(apply_local):
     return apply
 
 
-def folded_positions(inv: AttentionInvocation):
+def folded_positions(inv: AttentionInvocation, kv_heads=None):
     """(q_positions, kv_positions) repeated per folded head row.
 
     ``fold_heads`` lays rows out batch-major (row = b * H + h), so repeating
     each sequence's position vector H times yields the per-row positions the
-    kernels and oracles consume.  Falls back to the contiguous default
-    (``None``) when the orchestration layer provided no positions.
+    kernels and oracles consume.  Key positions repeat ``kv_heads`` times
+    (default H, for K/V repeated to query heads).  Falls back to the
+    contiguous default (``None``) when the orchestration layer provided no
+    positions.
     """
     h = inv.q.shape[2]
     q_pos = kv_pos = None
     if inv.q_positions is not None:
         q_pos = jnp.repeat(jnp.asarray(inv.q_positions, jnp.int32), h, axis=0)
     if inv.kv_positions is not None:
-        kv_pos = jnp.repeat(jnp.asarray(inv.kv_positions, jnp.int32), h, axis=0)
+        kv_pos = jnp.repeat(
+            jnp.asarray(inv.kv_positions, jnp.int32), kv_heads or h, axis=0
+        )
     return q_pos, kv_pos
+
+
+def packed_fused_decode(inv: AttentionInvocation, head0, kernel, name: str):
+    """Shared body of the packed fused backends.
+
+    The query spikes are packed here (one token per step); the cached
+    ``(B, S, T, H_kv, W)`` planes fold to ``(T, B*H_kv, S, W)`` and reach
+    ``kernel`` at KV-head granularity, which tiles each KV head's query
+    group together and reads its words once.  One kernel call per time
+    step; seeds and query positions stay per query head row.
+    """
+    from repro.bitpack import pack_spikes
+
+    if inv.packed_k is None or inv.packed_v is None:
+        raise ValueError(f"{name} requires packed KV planes")
+    hd = inv.q.shape[-1]
+    qw = fold_heads(pack_spikes(inv.spike_q))          # (T, B*H, S_q, W)
+    kw = fold_heads(jnp.moveaxis(inv.packed_k, 2, 0))  # (T, B*H_kv, S, W)
+    vw = fold_heads(jnp.moveaxis(inv.packed_v, 2, 0))
+    t_steps = qw.shape[0]
+    b, h = inv.q.shape[0], inv.q.shape[2]
+    seeds = inv.seeds if inv.seeds is not None else jnp.zeros(b, jnp.uint32)
+    step_seeds = derive_step_row_seeds(seeds, t_steps, h, head0)
+    q_pos, kv_pos = folded_positions(inv, kv_heads=inv.packed_k.shape[3])
+    interpret = default_interpret()
+    outs = [
+        kernel(
+            qw[t],
+            kw[t],
+            vw[t],
+            step_seeds[t],
+            inv.causal,
+            inv.window,
+            DEFAULT_BLOCK_Q,
+            DEFAULT_BLOCK_K,
+            interpret,
+            q_positions=q_pos,
+            kv_positions=kv_pos,
+            d_k=hd,
+        )
+        for t in range(t_steps)
+    ]
+    return rate_decode(jnp.stack(outs), b, h)
 
 
 def folded_spike_trains(inv: AttentionInvocation, *, unpack_kv: bool = True):

@@ -19,15 +19,20 @@ TPU realisation of the SAU-array dataflow (paper Fig. 2/3, DESIGN.md §2):
     are compared against `u * D_K` / `u * visible` — no division on the
     sampling path, mirroring the shift-free hardware comparison.
 
-Operands beyond Q/K/V: a per-row uint32 seed vector (one stream per
-batch/head row) and per-row absolute position vectors for queries and keys.
-Position ``-1`` marks absent tokens (prefill padding, never-written cache
-rows); they are masked out of eq. 5 and excluded from the eq. 6 ``visible``
-normaliser, which the kernel accumulates across kv tiles in scratch.
+Operands beyond Q/K/V: uint32 seeds (one stream per query head row) and
+per-row absolute position vectors for queries and keys.  Position ``-1``
+marks absent tokens (prefill padding, never-written cache rows); they are
+masked out of eq. 5 and excluded from the eq. 6 ``visible`` normaliser,
+which the kernel accumulates across kv tiles in scratch.
 
 Grid: ``(B, num_q_tiles, num_kv_tiles)`` with the kv axis innermost
 (reduction).  The attention-count accumulator lives in a VMEM scratch tile
-and is sampled into output spikes when the last kv tile retires.
+and is sampled into output spikes when the last kv tile retires.  The dense
+entry has one query head per grid row and its seed in SMEM.  The packed
+entries tile the query rows of a whole KV-head group together (grid row =
+one KV head, the group's heads x tokens stacked into the query tile), so
+each KV head's words are read once per call; their seeds arrive as a
+per-query-row ``(block_q, 1)`` vector beside the positions.
 """
 from __future__ import annotations
 
@@ -66,7 +71,7 @@ POS_STRIDE_A = np.uint32(0x85EBCA77)
 
 
 def _ssa_tile_body(
-    seed_ref,
+    seed,           # uint32 scalar, or (block_q, 1) one per query row
     qpos_ref,
     kvpos_ref,
     out_ref,
@@ -87,8 +92,9 @@ def _ssa_tile_body(
     """Shared eq. 5/6 tile math: the dense and packed kernels differ only in
     how the Q/K/V tiles reach VMEM (f32 lanes vs uint32 words unpacked here);
     everything downstream — counts, masks, counter-RNG indices — is identical,
-    which is what makes the packed path bit-exact vs the dense one."""
-    b = pl.program_id(0)
+    which is what makes the packed path bit-exact vs the dense one.  A
+    per-row seed vector broadcasts over the tile exactly as the scalar does,
+    so every draw keeps its (seed, position) counter."""
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -117,7 +123,7 @@ def _ssa_tile_body(
     qp_u = jnp.maximum(qp, 0).astype(jnp.uint32)
     kp_u = jnp.maximum(kp, 0).astype(jnp.uint32)
     idx_s = qp_u * POS_STRIDE_S + kp_u
-    u_s = uniform_from_counter(seed_ref[b, 0] ^ SALT_S, idx_s)
+    u_s = uniform_from_counter(seed ^ SALT_S, idx_s)
     s = (valid & (u_s * jnp.float32(d_k) < counts_s)).astype(jnp.float32)
 
     # ---- eq. 6 partials: acc += S-tile @ V-tile; vis += |valid| -----------
@@ -131,7 +137,7 @@ def _ssa_tile_body(
     def _finalize():
         col = jax.lax.broadcasted_iota(jnp.uint32, (block_q, d_pad), 1)
         idx_a = qp_u * POS_STRIDE_A + col
-        u_a = uniform_from_counter(seed_ref[b, 0] ^ SALT_A, idx_a)
+        u_a = uniform_from_counter(seed ^ SALT_A, idx_a)
         visible = jnp.maximum(vis_ref[...], 1.0)        # (block_q, 1)
         out = (u_a * visible < acc_ref[...]).astype(out_ref.dtype)
         out_ref[0] = out
@@ -141,9 +147,10 @@ def _ssa_kernel(
     seed_ref, qpos_ref, kvpos_ref, q_ref, k_ref, v_ref, out_ref,
     acc_ref, vis_ref, **geom,
 ):
-    """Dense entry point: Q/K/V tiles arrive as 0/1 lanes."""
+    """Dense entry point: Q/K/V tiles arrive as 0/1 lanes; one seed per grid
+    row, from SMEM."""
     _ssa_tile_body(
-        seed_ref,
+        seed_ref[pl.program_id(0), 0],
         qpos_ref,
         kvpos_ref,
         out_ref,
@@ -163,9 +170,10 @@ def _ssa_kernel_packed(
     """Packed entry point: tiles arrive as uint32 words (1 bit/spike in HBM)
     and expand to MXU lanes only here, in VMEM.  w_pad * 32 == d_pad, so the
     unpacked tiles have exactly the dense kernel's geometry and the shared
-    body (same counter-RNG indices) produces bit-identical spikes."""
+    body (same counter-RNG indices) produces bit-identical spikes.  Seeds
+    come per query row: a tile holds several heads of one KV-head group."""
     _ssa_tile_body(
-        seed_ref,
+        seed_ref[0],
         qpos_ref,
         kvpos_ref,
         out_ref,
@@ -201,8 +209,8 @@ def _sdsa_kernel_packed(
     anywhere on the value path).  Counter-RNG indices reuse the output-bank
     position stride, salted with ``SALT_SDSA`` so the stream is independent
     of the SSA banks while staying request-addressed (RNG contract v2).
+    Seeds arrive per query row, as in the packed SSA entry.
     """
-    b = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -234,7 +242,7 @@ def _sdsa_kernel_packed(
         qp_u = jnp.maximum(qp, 0).astype(jnp.uint32)
         col = jax.lax.broadcasted_iota(jnp.uint32, (block_q, d_pad), 1)
         idx = qp_u * POS_STRIDE_A + col
-        u = uniform_from_counter(seed_ref[b, 0] ^ SALT_SDSA, idx)
+        u = uniform_from_counter(seed_ref[0] ^ SALT_SDSA, idx)
         visible = jnp.maximum(vis_ref[...], 1.0)        # (block_q, 1)
         s = (u * visible < acc_ref[...]).astype(jnp.float32)
         q_lanes = unpack_words_to_lanes(q_ref[0])
@@ -259,13 +267,15 @@ def build_ssa_pallas(
 ):
     """Construct the pallas_call for a given padded geometry.
 
-    Call signature: ``call(seeds, q_pos, kv_pos, q, k, v)`` with
-    ``seeds (B, 1)`` uint32 in SMEM and positions as ``(B, n_q_pad, 1)`` /
-    ``(B, 1, n_kv_pad)`` int32 (pad value -1 => masked).  ``packed=True``
-    takes Q/K/V as uint32 bit-planes of width ``w_pad = d_pad // 32`` (see
-    ``repro.bitpack``); output spikes stay dense — bit-identical to the
-    dense kernel for the same seeds/positions.  ``variant="sdsa"`` swaps in
-    the addition-only spike-driven tile body (packed operands only; same
+    Call signature: ``call(seeds, q_pos, kv_pos, q, k, v)`` with positions
+    as ``(B, n_q_pad, 1)`` / ``(B, 1, n_kv_pad)`` int32 (pad value -1 =>
+    masked).  Dense: ``seeds (B, 1)`` uint32 in SMEM, one per grid row.
+    ``packed=True`` takes Q/K/V as uint32 bit-planes of width ``w_pad =
+    d_pad // 32`` (see ``repro.bitpack``) and ``seeds (B, n_q_pad, 1)``,
+    one per query row, so a grid row may stack the query heads that share
+    its K/V row; output spikes stay dense — bit-identical to the dense
+    kernel for the same seeds/positions.  ``variant="sdsa"`` swaps in the
+    addition-only spike-driven tile body (packed operands only; same
     operand/grid signature, so the wrapper padding is shared)."""
     num_q_tiles = cdiv(n_q_pad, block_q)
     num_kv_tiles = cdiv(n_kv_pad, block_k)
@@ -291,11 +301,15 @@ def build_ssa_pallas(
     )
 
     d_in = d_pad // 32 if packed else d_pad
+    if packed:      # seeds (B, n_q_pad, 1), one per query row
+        seed_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
+    else:           # seeds (B, 1), one per grid row
+        seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=(bsz, num_q_tiles, num_kv_tiles),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # seeds (B, 1)
+            seed_spec,
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, j)),
             pl.BlockSpec((1, block_q, d_in), lambda b, i, j: (b, i, 0)),

@@ -25,9 +25,10 @@ import jax.numpy as jnp
 
 from repro.obs import trace_scope
 
-from ..common import uniform_from_counter
+from ..common import cdiv, uniform_from_counter
 from .kernel import SALT_S, build_ssa_pallas
 from .ref import (
+    ensure_positions,
     normalize_seed_positions,
     padded_dims,
     score_counter_idx,
@@ -107,7 +108,11 @@ def ssa_attention(
     ``repro.bitpack.pack_spikes`` and ``d_k`` must be given; HBM traffic is
     1 bit/spike, words unpack to MXU tiles in VMEM, and the output (dense
     0/1 spikes, (B, N_q, D_K)) is bit-identical to the dense path for the
-    same seeds/positions.  The packed path is inference-only.
+    same seeds/positions.  Packed k/v (and ``kv_positions``) may have fewer
+    rows than q, one per KV head: ``B_q = B_kv * groups`` query rows in
+    ``fold_heads`` order, the ``groups`` heads of a KV head tiled together
+    against its words, read once (``block_q`` then caps the query tile).
+    The packed path is inference-only.
     """
     if not packed:
         return _ssa_attention_dense(
@@ -151,11 +156,34 @@ def sdsa_attention(
     )
 
 
+def _packed_tiles(rows: int, n_kv: int, block_q: int, block_k: int):
+    """Tile geometry of a packed call, from its shapes.
+
+    The query tile holds ``rows`` (a KV-head group's heads x tokens) padded
+    to the 8-row sublane tile, up to ``block_q`` rows and tiled beyond.  The
+    key axis pads to ``block_k`` and its tile spans up to four such blocks
+    where they divide it: with only a few query rows a tile, narrow kv
+    tiles would leave the grid mostly step overhead.
+    Returns ``(block_q, rows_pad, block_k, n_kv_pad)``."""
+    block_q = min(block_q, cdiv(rows, 8) * 8)
+    n_kv_pad = cdiv(n_kv, block_k) * block_k
+    block_k = next(
+        block_k * m for m in (4, 2, 1) if n_kv_pad % (block_k * m) == 0
+    )
+    return block_q, cdiv(rows, block_q) * block_q, block_k, n_kv_pad
+
+
 def _packed_attention(variant, q, k, v, seed, causal, window,
                       block_q, block_k, interpret,
                       q_positions, kv_positions, d_k):
-    """Shared packed-operand dispatch: validate bit-plane widths, pad to
-    tile boundaries, build the requested kernel variant."""
+    """Shared packed-operand dispatch: validate bit-plane widths, tile the
+    query rows of each KV-head group together, build the kernel variant.
+
+    ``q`` has ``B_q = B_kv * groups`` rows in ``fold_heads`` order (row
+    ``b * H + h`` reads K/V row ``b * H_kv + h // groups``); ``seed`` is a
+    scalar or ``(B_q,)``, ``q_positions`` ``(B_q, N_q)``, ``kv_positions``
+    ``(B_kv, N_kv)``.  The ``groups`` consecutive query rows of a K/V row
+    become one ``(groups * N_q)``-row query tile against it."""
     if d_k is None:
         raise ValueError("packed=True requires d_k (unpadded feature size)")
     from repro.bitpack import packed_width
@@ -170,19 +198,31 @@ def _packed_attention(variant, q, k, v, seed, causal, window,
                 f"packed {name} width {arr.shape[-1]} inconsistent with "
                 f"d_k={d_k} (expected {packed_width(d_k)})"
             )
-    bsz, n_q, _ = q.shape
-    n_kv = k.shape[1]
-    n_q_pad, n_kv_pad, d_pad = padded_dims(n_q, n_kv, d_k, block_q, block_k)
-    w_pad = d_pad // 32
-    seeds, q_pos, kv_pos = _norm_inputs(
-        seed, q_positions, kv_positions, bsz, n_q, n_kv
+    b_q, n_q, _ = q.shape
+    b_kv, n_kv, _ = k.shape
+    if v.shape != k.shape or b_q % b_kv:
+        raise ValueError(
+            f"packed k {k.shape} / v {v.shape} do not group q {q.shape}: "
+            "k and v must match and divide the query rows"
+        )
+    rows = (b_q // b_kv) * n_q
+    block_q, rows_pad, block_k, n_kv_pad = _packed_tiles(
+        rows, n_kv, block_q, block_k
     )
-    qp = _pad3(q, n_q_pad, w_pad)
-    kp = _pad3(k, n_kv_pad, w_pad)
-    vp = _pad3(v, n_kv_pad, w_pad)
+    d_pad = cdiv(d_k, 128) * 128
+    w_pad = d_pad // 32
+    seeds = jnp.broadcast_to(jnp.asarray(seed, jnp.uint32).reshape(-1), (b_q,))
+    q_pos, kv_pos = ensure_positions(q_positions, kv_positions, 1, n_q, n_kv)
+    q_pos = jnp.broadcast_to(q_pos, (b_q, n_q))
+    kv_pos = jnp.broadcast_to(kv_pos, (b_kv, n_kv))
+
+    def grouped(x):     # (B_q, N_q, ...) -> (B_kv, groups * N_q, ...)
+        return x.reshape(b_kv, rows, *x.shape[2:])
+
+    row_seeds = grouped(jnp.broadcast_to(seeds[:, None], (b_q, n_q)))
     call = build_ssa_pallas(
-        bsz=bsz,
-        n_q_pad=n_q_pad,
+        bsz=b_kv,
+        n_q_pad=rows_pad,
         n_kv_pad=n_kv_pad,
         d_k=d_k,
         d_pad=d_pad,
@@ -197,14 +237,14 @@ def _packed_attention(variant, q, k, v, seed, causal, window,
     )
     with trace_scope(f"repro/kernels/{variant}_attention"):
         out = call(
-            seeds.reshape(bsz, 1),
-            _pad_pos(q_pos, n_q_pad)[:, :, None],
+            _pad3(row_seeds[:, :, None], rows_pad, 1),
+            _pad_pos(grouped(q_pos), rows_pad)[:, :, None],
             _pad_pos(kv_pos, n_kv_pad)[:, None, :],
-            qp,
-            kp,
-            vp,
+            _pad3(grouped(q), rows_pad, w_pad),
+            _pad3(k, n_kv_pad, w_pad),
+            _pad3(v, n_kv_pad, w_pad),
         )
-    return out[:, :n_q, :d_k]
+    return out[:, :rows, :d_k].reshape(b_q, n_q, d_k)
 
 
 @functools.partial(

@@ -380,6 +380,46 @@ def test_packed_fused_decode_never_unpacks_cached_planes():
     assert unpacked in text_x or transposed in text_x
 
 
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation of a jaxpr, inside scans and jits too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _pallas_calls(sub)
+
+
+def test_packed_fused_gqa_decode_reads_each_kv_head_once():
+    """Grouped-query decode (2 query heads per KV head): the packed SSA
+    kernel's K/V operands keep one row per (sequence, KV head) — no plane
+    is repeated to query heads — and the query words (operand 3, the one
+    the benchmark's kernel reader keys on) stay uint32, grouped to match."""
+    b, max_seq = 2, 32
+    cfg = _ssa_cfg("fused", "packed", attention__num_kv_heads=2)
+    a = cfg.attention
+    assert a.num_kv_heads < a.num_heads
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {
+        "tokens": jnp.zeros((b, 1), jnp.int32),
+        "positions": jnp.full((b, 1), 4, jnp.int32),
+    }
+    jaxpr = jax.make_jaxpr(model.decode_step)(
+        params, batch, model.init_cache(b, max_seq), jnp.full((b,), 4, jnp.int32)
+    )
+    calls = list(_pallas_calls(jaxpr.jaxpr))
+    assert calls
+    for eqn in calls:
+        seeds, q_pos, kv_pos, q, k, v = (x.aval for x in eqn.invars)
+        assert q.dtype == jnp.uint32
+        assert k.shape[0] == v.shape[0] == kv_pos.shape[0] == b * a.num_kv_heads
+        assert q.shape[0] == b * a.num_kv_heads
+        assert seeds.shape == q_pos.shape  # one seed per query row
+
+
 # ---------------------------------------------------------------------------
 # serving-engine token identity across backends
 # ---------------------------------------------------------------------------

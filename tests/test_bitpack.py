@@ -1,7 +1,9 @@
 """Bit-plane spike subsystem: pack/unpack round trips, popcount-matmul
 equivalence (ref + Pallas interpret), packed-vs-dense fused-SSA bit-identity,
-and the packed spiking KV cache end to end."""
+the grouped-query packed tile against the oracles, and the packed spiking KV
+cache end to end."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +18,8 @@ from repro.bitpack import (
     unpack_spikes,
 )
 from repro.kernels.popcount_matmul import popcount_matmul
-from repro.kernels.ssa_attention.ops import ssa_attention
+from repro.kernels.ssa_attention.ops import sdsa_attention, ssa_attention
+from repro.kernels.ssa_attention.ref import sdsa_reference, ssa_reference
 
 INTERP = True  # CPU container: Pallas kernels run in interpret mode
 
@@ -136,6 +139,59 @@ def test_packed_ssa_bit_identical_to_dense(b, n_q, n_kv, d, causal, window):
     )
 
 
+# ---------------------------------------------------------------------------
+# grouped packed tile: B_q = B_kv x groups query rows against KV-head planes
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("n_kv", [96, 300, 2048])
+@pytest.mark.parametrize("n_q", [1, 4, 16])
+@pytest.mark.parametrize("groups", [1, 2, 8])
+@pytest.mark.parametrize("kernel", ["ssa", "sdsa"])
+def test_packed_grouped_bit_identical_to_reference(kernel, groups, n_q, n_kv):
+    """A packed call whose K/V planes hold one row per KV head (the query
+    heads of a group tiled together) samples exactly what the oracle
+    samples on K/V trains repeated to every query head: per-row seeds of a
+    head slice (head0 != 0), two sequences of different lengths, holes."""
+    from repro.attention.base import derive_step_row_seeds
+
+    b, h_kv, d = 2, 2, 40
+    h = h_kv * groups
+    key = jax.random.PRNGKey(groups * 1000 + n_q * 10 + n_kv)
+    q = _spikes(key, (b * h, n_q, d), 0.4)
+    k = _spikes(jax.random.fold_in(key, 1), (b * h_kv, n_kv, d), 0.6)
+    v = _spikes(jax.random.fold_in(key, 2), (b * h_kv, n_kv, d), 0.5)
+    # sequence 0 fills the cache, sequence 1 stops 40 rows short; both have
+    # never-written rows (-1) inside the span
+    lengths = np.array([n_kv, n_kv - 40])
+    kv_seq = np.where(
+        np.arange(n_kv)[None] < lengths[:, None], np.arange(n_kv)[None], -1
+    )
+    kv_seq[:, 3:7] = -1
+    q_seq = lengths[:, None] - n_q + np.arange(n_q)[None]
+    kv_pos = jnp.asarray(np.repeat(kv_seq, h_kv, axis=0), jnp.int32)
+    q_pos = jnp.asarray(np.repeat(q_seq, h, axis=0), jnp.int32)
+    seeds = derive_step_row_seeds(
+        jnp.asarray([0x1234, 0xBEEF], jnp.uint32), 2, h, head0=3
+    )[1]
+    fused, ref = {
+        "ssa": (functools.partial(ssa_attention, packed=True), ssa_reference),
+        "sdsa": (sdsa_attention, sdsa_reference),
+    }[kernel]
+    out = fused(
+        pack_spikes(q), pack_spikes(k), pack_spikes(v), seeds, True, None,
+        128, 128, INTERP, q_positions=q_pos, kv_positions=kv_pos, d_k=d,
+    )
+    want = ref(
+        q, jnp.repeat(k, groups, axis=0), jnp.repeat(v, groups, axis=0),
+        seeds, causal=True, q_positions=q_pos,
+        kv_positions=jnp.repeat(kv_pos, groups, axis=0),
+    )
+    assert out.shape == (b * h, n_q, d)
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32), np.asarray(want, np.float32)
+    )
+    assert 0 < float(np.asarray(want).mean()) < 1
+
+
 def test_packed_ssa_rejects_bad_inputs():
     q = jnp.zeros((1, 8, 2), jnp.uint32)
     with pytest.raises(ValueError):
@@ -151,6 +207,13 @@ def test_packed_ssa_rejects_bad_inputs():
         ssa_attention(q, k_narrow, q, jnp.uint32(0), packed=True, d_k=64)
     with pytest.raises(TypeError):
         ssa_attention(q, q, qf, jnp.uint32(0), packed=True, d_k=64)
+    # K/V rows must divide the query rows (one per KV head) and match
+    q3 = jnp.zeros((3, 8, 2), jnp.uint32)
+    k2 = jnp.zeros((2, 8, 2), jnp.uint32)
+    with pytest.raises(ValueError):
+        ssa_attention(q3, k2, k2, jnp.uint32(0), packed=True, d_k=64)
+    with pytest.raises(ValueError):
+        ssa_attention(q, q, k2, jnp.uint32(0), packed=True, d_k=64)
 
 
 # ---------------------------------------------------------------------------

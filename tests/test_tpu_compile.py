@@ -8,6 +8,7 @@ unsupported cast, a misaligned block, too much VMEM) fails here instead of
 on the first chip run.  The topology is described inside a fixture, never
 at import, so every test worker collects the same tests.
 """
+import functools
 import os
 
 import jax
@@ -48,6 +49,7 @@ def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def test_ssa_dense_prefill_compiles(one_chip):
@@ -86,6 +88,36 @@ def test_packed_decode_compiles(one_chip, kernel):
         ((ROWS, N_KV, WORDS), jnp.uint32),
         ((ROWS,), jnp.uint32),
     )
+
+
+@pytest.mark.parametrize("n_q", [1, 16], ids=["decode", "chunk"])
+@pytest.mark.parametrize("kernel", ["ssa", "sdsa"])
+def test_packed_grouped_compiles(one_chip, kernel, n_q):
+    # codeqwen1.5-7b serving: 8 requests x 32 query heads over 4 KV heads;
+    # a KV head's 8 query heads share a tile: 8 rows for a decode token,
+    # 128 for a 16-token prefill chunk
+    b, h, h_kv = 8, 32, 4
+    attend = {
+        "ssa": functools.partial(ssa_attention, packed=True),
+        "sdsa": sdsa_attention,
+    }[kernel]
+
+    def fn(q, k, v, s, q_pos, kv_pos):
+        return attend(q, k, v, s, causal=True, q_positions=q_pos,
+                      kv_positions=kv_pos, d_k=HD)
+
+    text = _compile(
+        fn,
+        one_chip,
+        ((b * h, n_q, WORDS), jnp.uint32),
+        ((b * h_kv, N_KV, WORDS), jnp.uint32),
+        ((b * h_kv, N_KV, WORDS), jnp.uint32),
+        ((b * h,), jnp.uint32),
+        ((b * h, n_q), jnp.int32),
+        ((b * h_kv, N_KV), jnp.int32),
+    )
+    # one grid row per KV head, its group's rows unpadded in the tile
+    assert f"f32[{b * h_kv},{h // h_kv * n_q},{HD}]" in text
 
 
 @pytest.mark.parametrize(

@@ -37,6 +37,7 @@ from .base import (
     resolve_backend,
     resolve_backend_name,
     unfold_heads,
+    window_ring,
 )
 from .encoding import spike_encode
 
@@ -68,6 +69,7 @@ __all__ = [
     "get_backend",
     "is_paged_cache",
     "paged_extent",
+    "window_ring",
     "record_backends",
     "register_backend",
     "resolve_backend",

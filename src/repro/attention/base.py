@@ -73,6 +73,7 @@ __all__ = [
     "default_interpret",
     "is_paged_cache",
     "paged_extent",
+    "window_ring",
     "gather_pages",
     "next_pow2",
     "bucketed_table_width",
@@ -399,6 +400,20 @@ def is_paged_cache(cache: Optional[dict]) -> bool:
     return cache is not None and "bt" in cache
 
 
+def window_ring(window: int, page_size: int) -> int:
+    """Rows of a paged sliding-window layer's rolling ring: the window plus
+    one page, rounded up to whole pages (1024 + 16 -> 1040).
+
+    A prefix-extend chunk writes all its tokens before its queries attend,
+    so a ring of exactly ``window`` rows would let the chunk's last writes
+    evict keys its first queries still see.  With the extra page, a chunk
+    of up to ``ring - window + 1`` tokens (one page at least) only evicts
+    keys below every one of its queries' windows; the attention masks
+    ``kp > qp - window`` by absolute position, so the extra rows are never
+    attended."""
+    return -(-(window + page_size) // page_size) * page_size
+
+
 def paged_extent(cache: dict, layer_window: Optional[int]) -> int:
     """Logical contiguous extent a paged layer cache stands in for.
 
@@ -406,12 +421,14 @@ def paged_extent(cache: dict, layer_window: Optional[int]) -> int:
     a growth-bucketed table width for *every* impl (all backends are
     position-masked and extent-invariant since RNG contract v2, spiking
     included), so the span covers the allocated pages, not ``max_seq``.
-    Sliding-window layers: clamped to the window, matching the slab
-    layout's ``S_cache = min(window, max_seq)`` rolling extent.
+    Sliding-window layers: the :func:`window_ring` (position ``p`` at ring
+    row ``p % ring``), clamped to the span.
     """
     page_size = cache["pos"].shape[-1]
     span = cache["bt"].shape[-1] * page_size
-    return span if layer_window is None else min(layer_window, span)
+    if layer_window is None:
+        return span
+    return min(window_ring(layer_window, page_size), span)
 
 
 def next_pow2(n: int) -> int:

@@ -9,6 +9,7 @@ from .base import (
     ShapeConfig,
     TrainConfig,
     XLSTMConfig,
+    YarnConfig,
     with_overrides,
 )
 from .registry import ARCH_IDS, cells, get_config, get_shape, get_smoke_config
@@ -23,6 +24,7 @@ __all__ = [
     "ShapeConfig",
     "TrainConfig",
     "XLSTMConfig",
+    "YarnConfig",
     "with_overrides",
     "ARCH_IDS",
     "cells",

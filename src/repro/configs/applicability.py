@@ -23,6 +23,7 @@ _LONG_SKIP = {
     "qwen2_vl_2b": "pure full attention",
     "deepseek_moe_16b": "pure full attention",
     "whisper_small": "enc-dec, decoder max target 448; no 500k decode semantics",
+    "mellum2_12b_a2_5b": "every fourth layer is full attention (window 1024 elsewhere)",
 }
 
 

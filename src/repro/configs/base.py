@@ -17,6 +17,19 @@ from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (HF ``rope_type="yarn"``): frequency pairs past
+    the ``beta_fast``/``beta_slow`` correction range are interpolated by
+    ``factor``, a linear ramp blends the ones inside it, and cos/sin are
+    scaled by ``attention_factor``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
 class AttentionConfig:
     num_heads: int
     num_kv_heads: int
@@ -27,6 +40,9 @@ class AttentionConfig:
     impl: str = "ann"                 # ann | ssa | spikformer | sdsa | qksum
     rope_theta: float = 10_000.0
     rope_type: str = "rope"           # rope | mrope | none
+    # YaRN scaling of the global ("G") layers' RoPE; window ("L") layers
+    # keep plain RoPE at the same theta (mellum2's per-kind rope_parameters)
+    global_yarn: Optional[YarnConfig] = None
     softcap: Optional[float] = None   # gemma2 attn logit soft-capping (ANN only)
     sliding_window: Optional[int] = None
     # layer i is local (sliding-window) iff pattern[i % len(pattern)] == "L"

@@ -19,6 +19,7 @@ ARCH_IDS = [
     "zamba2_1_2b",
     "whisper_small",
     "spiking_vit_small",   # the paper's own architecture
+    "mellum2_12b_a2_5b",
 ]
 
 _ALIASES = {
@@ -33,6 +34,7 @@ _ALIASES = {
     "zamba2-1.2b": "zamba2_1_2b",
     "whisper-small": "whisper_small",
     "spiking-vit-small": "spiking_vit_small",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
 }
 
 

@@ -1,11 +1,13 @@
 """Composable model building blocks (pure-functional, pytree params).
 
-Everything the 10 assigned architectures need: RMS/LayerNorm, RoPE / M-RoPE,
+Everything the assigned architectures need: RMS/LayerNorm, RoPE (plain or
+YaRN) / M-RoPE,
 GQA attention with three interchangeable implementations (`ann` softmax /
 `ssa` the paper's stochastic spiking attention / `spikformer` baseline) —
 each realised by a backend from the `repro.attention` registry (XLA
 reference or fused Pallas kernels, `AttentionConfig.backend`) —
-SwiGLU/GeGLU/GELU MLPs, and MoE (shared + routed experts, top-k).
+SwiGLU/GeGLU/GELU MLPs, and MoE (shared + routed experts, top-k: dropless
+grouped-matmul dispatch when serving, capacity dispatch in training).
 
 Conventions:
   * params are nested dicts of jnp arrays; layer stacks add a leading L axis
@@ -15,6 +17,7 @@ Conventions:
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -31,7 +34,7 @@ from repro.attention import (
     spike_encode,
 )
 from repro.attention.ann_xla import sdpa as _sdpa, sdpa_chunked as _sdpa_chunked
-from repro.configs.base import AttentionConfig, ModelConfig, MoEConfig
+from repro.configs.base import AttentionConfig, ModelConfig, MoEConfig, YarnConfig
 from repro.distributed.sharding import constrain
 from repro.obs import trace_scope
 
@@ -99,12 +102,46 @@ def _rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, hd); positions: (B, S) int32."""
-    freqs = _rope_freqs(x.shape[-1], theta)
+def yarn_inv_freq(head_dim: int, theta: float, yarn: YarnConfig) -> np.ndarray:
+    """YaRN frequencies (HF ``_compute_yarn_parameters``), float32.
+
+    Pair ``i`` extrapolates (``theta^(-2i/hd)``) below the correction range
+    ``[low, high]`` and interpolates (``/ factor``) above it, with a linear
+    ramp between; ``low``/``high`` are the pairs that turn ``beta_fast`` /
+    ``beta_slow`` times over ``original_max_position_embeddings`` positions,
+    floored and ceiled.  Computed in float64 on the host."""
+    dim = head_dim
+    extrap = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    interp = extrap / yarn.factor
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    return (interp * ramp + extrap * (1.0 - ramp)).astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: Optional[YarnConfig] = None) -> jax.Array:
+    """x: (B, S, H, hd); positions: (B, S) int32.  ``yarn``: YaRN
+    frequencies, with cos and sin scaled by its ``attention_factor``."""
+    if yarn is None:
+        freqs = _rope_freqs(x.shape[-1], theta)
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(x.shape[-1], theta, yarn))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, hd/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if yarn is not None:
+        scale = np.float32(yarn.attention_factor)
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -205,7 +242,8 @@ def _cache_write(
     position is ``-1`` (pad rows of a bucketed chunk) are dropped so page /
     cache rows beyond the real tokens keep their pristine fill.  prefill
     (``cache_index is None``): fill [0:s], keeping the tail when the update
-    overflows the window.
+    overflows the cache; a window layer's tail is rolled so position ``p``
+    sits at row ``p % S_cache``, where decode's rolled writes expect it.
 
     Paged caches (leaves ``(num_pages, page_size, ...)`` plus a block table
     ``bt: (B, W)``) support the decode/prefix-extend paths only: each
@@ -300,10 +338,15 @@ def _cache_write(
                     leaf, upd.astype(leaf.dtype), start
                 )
     else:
+        s_upd = updates["pos"].shape[1]
         for name, upd in updates.items():
             leaf = cache[name]
-            if upd.shape[1] >= s_cache:
+            if s_upd >= s_cache:
                 upd = upd[:, -s_cache:]
+                if layer_window is not None:
+                    # rolled as decode writes it: position p at row
+                    # p % S_cache, so the next write evicts the oldest row
+                    upd = jnp.roll(upd, s_upd % s_cache, axis=1)
             new[name] = jax.lax.dynamic_update_slice(
                 leaf, upd.astype(leaf.dtype), (0,) * leaf.ndim
             )
@@ -356,9 +399,11 @@ def attention_apply(
     v = (kv_in @ p["wv"]).reshape(b, s_kv, a.num_kv_heads, a.head_dim)
 
     if a.rope_type == "rope":
-        q = apply_rope(q, positions, a.rope_theta)
+        # per-kind RoPE: YaRN on global layers where the model states it
+        yarn = a.global_yarn if layer_window is None else None
+        q = apply_rope(q, positions, a.rope_theta, yarn)
         if kv_source is None:
-            k = apply_rope(k, positions, a.rope_theta)
+            k = apply_rope(k, positions, a.rope_theta, yarn)
     elif a.rope_type == "mrope":
         q = apply_mrope(q, positions, a.rope_theta)
         if kv_source is None:
@@ -571,9 +616,97 @@ def moe_params(key, d_model: int, moe: MoEConfig, act: str, dtype=jnp.float32) -
     return p
 
 
-def moe_apply(p: dict, x: jax.Array, moe: MoEConfig, act: str, capacity_factor: float = 1.25):
-    """Top-k routed experts, *per-sequence-row* sort-based dispatch.
+def _grouped_ffn(rows, wg, wi, wo, sizes, layer):
+    """SwiGLU of expert-sorted rows through three ``moe_gmm`` calls.
 
+    XLA cannot partition a Mosaic kernel, so under an active sharding mesh
+    (``distributed.sharding.current_rules``: serving tensor parallelism)
+    the three calls run inside ``shard_map``, whole on every shard: serving
+    TP keeps the weights and the residual stream replicated, and splitting
+    the ffn axis would need a float psum over ``wo``, which reorders sums.
+    """
+    from repro.attention import default_interpret
+    from repro.distributed.sharding import current_rules
+    from repro.kernels.moe_gmm import moe_gmm
+    from jax.sharding import PartitionSpec as P
+
+    def ffn(rows, wg, wi, wo, sizes, layer):
+        kw = dict(layer=layer, interpret=default_interpret())
+        g = moe_gmm(rows, wg, sizes, **kw)
+        u = moe_gmm(rows, wi, sizes, **kw)
+        return moe_gmm(jax.nn.silu(g) * u, wo, sizes, **kw)
+
+    args = (rows, wg, wi, wo, sizes, layer)
+    rules = current_rules()
+    if rules is None:
+        return ffn(*args)
+    return jax.shard_map(ffn, mesh=rules.mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
+
+
+def moe_dropless(p: dict, x: jax.Array, moe: MoEConfig, valid: jax.Array,
+                 layer=None) -> tuple[jax.Array, jax.Array]:
+    """Dropless top-k experts over every token of the call (serving).
+
+    Softmax router in float32 (``HIGHEST`` precision), top-k, the k
+    weights renormalised to sum to 1.  Every (token, expert) pair of the
+    call — all rows, all tokens of a chunk — is sorted by expert and run
+    through the grouped matmul (``kernels.moe_gmm``), which visits only
+    experts with rows; the k outputs of a token are combined in float32 in
+    rank order.  A token's output depends on that token alone, so one-shot
+    prefill, chunked prefill and decode compute the same rows, and no pair
+    is ever dropped.  Tokens with ``valid`` False (pad positions) route
+    nowhere and come out 0.
+
+    ``layer``: where the expert matrices come stacked ``(L, E, ...)``
+    (a scanned layer stack), the index of this layer (may be traced).
+
+    Returns ``(out (B, S, d), stats)``: ``stats`` int32 ``(2,)`` = (routed
+    pairs, experts with at least one row) of this call.
+    """
+    b, s, d = x.shape
+    e, k = moe.num_experts, moe.top_k
+    n = b * s
+    xf = x.reshape(n, d)
+    live = valid.reshape(n)
+    with trace_scope("repro/moe_route"):
+        logits = jnp.dot(xf.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+        # pairs of dead tokens sort last, past every expert's rows
+        expert = jnp.where(live[:, None], top_i, e).reshape(n * k)
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.bincount(expert, length=e + 1)[:e].astype(jnp.int32)
+        rows = xf[order // k]
+    with trace_scope("repro/moe_experts"):
+        y = _grouped_ffn(rows, p["wg"], p["wi"], p["wo"], sizes, layer)
+        # back to (token, rank) order, then combine ranks 0..k-1 in turn
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32))
+        y = y[back].reshape(n, k, d)
+        out = top_p[:, 0:1] * y[:, 0].astype(jnp.float32)
+        for r in range(1, k):
+            out = out + top_p[:, r:r + 1] * y[:, r].astype(jnp.float32)
+        out = jnp.where(live[:, None], out, 0.0).astype(x.dtype)
+    stats = jnp.stack([live.sum() * k, (sizes > 0).sum()]).astype(jnp.int32)
+    return out.reshape(b, s, d), stats
+
+
+def moe_apply(p: dict, x: jax.Array, moe: MoEConfig, act: str,
+              capacity_factor: float = 1.25, *,
+              valid: Optional[jax.Array] = None, layer=None):
+    """Top-k routed experts (+ shared experts).
+
+    Serving (``valid`` given: (B, S) bool, False on pad tokens — every
+    call with a KV cache passes it) is **dropless**: :func:`moe_dropless`
+    computes each token's k experts whatever the batch, and the second
+    return value is its int32 ``(2,)`` stats (routed pairs, experts with
+    rows); ``layer`` indexes expert matrices that come stacked.  Training
+    (``valid`` None) keeps the capacity dispatch below and returns the
+    load-balancing aux loss.
+
+    Capacity dispatch: *per-sequence-row* sort-based.
     Routing, capacity ranking and the scatter/gather all happen within each
     batch row (vmapped over B): under GSPMD the B axis is data-sharded, so
     the sort and scatters stay shard-local -- a global flat dispatch forces
@@ -584,6 +717,11 @@ def moe_apply(p: dict, x: jax.Array, moe: MoEConfig, act: str, capacity_factor: 
     C = ceil(S*K*cf/E) per row; overflow drops (Switch-style).  Returns
     (out, aux_loss).
     """
+    if valid is not None:
+        out, stats = moe_dropless(p, x, moe, valid, layer)
+        if moe.num_shared_experts:
+            out = out + mlp_apply(p["shared"], x, act)
+        return out, stats
     from repro.distributed.sharding import constrain as _constrain
 
     b, s, d = x.shape

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.attention import derive_request_seeds, fold_layer_seeds
+from repro.attention import derive_request_seeds, fold_layer_seeds, window_ring
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed.sharding import constrain
 from repro.obs import trace_scope
@@ -134,7 +134,10 @@ class DecoderLM:
         return params
 
     # ------------------------------------------------------------------
-    def _block(self, p, x, *, slot, positions, seeds, cache, cache_index):
+    def _block(self, p, x, *, slot, positions, seeds, cache, cache_index,
+               step=None):
+        """One layer.  ``step``: with serving experts, this layer's index in
+        its slot's stack, whose expert matrices ``p["moe"]`` holds whole."""
         cfg = self.cfg
         h = norm_apply(p["ln_attn"], x, cfg.norm, cfg.norm_eps)
         attn_out, new_cache = attention_apply(
@@ -153,7 +156,12 @@ class DecoderLM:
         x = constrain(x, "btd_sp")
         h = norm_apply(p["ln_mlp"], x, cfg.norm, cfg.norm_eps)
         aux = 0.0
-        if cfg.moe:
+        if cfg.moe and cache is not None:
+            # serving: dropless, ``aux`` is the layer's int32 (2,) stats
+            pos = positions[0] if positions.ndim == 3 else positions
+            ff, aux = moe_apply(p["moe"], h, cfg.moe, cfg.act, valid=pos >= 0,
+                                layer=step)
+        elif cfg.moe:
             ff, aux = moe_apply(p["moe"], h, cfg.moe, cfg.act)
         else:
             ff = mlp_apply(p["mlp"], h, cfg.act)
@@ -173,7 +181,12 @@ class DecoderLM:
         seeds: Optional[jax.Array] = None,
         remat: str = "none",
     ):
-        """Returns (hidden (B,S,D), new_cache, aux_loss).
+        """Returns (hidden (B,S,D), new_cache, aux).
+
+        ``aux``: the load-balancing loss without a cache; with one, the
+        expert layers' int32 ``(num_layers, 2)`` stats in layer order
+        (routed pairs, experts with rows; ``moe_apply``), ``None`` for a
+        model without experts.
 
         ``seeds``: (B,) uint32 per-sequence SSA sampling seeds (RNG contract
         v2) — the serving engine passes each request's own seed so a
@@ -198,26 +211,52 @@ class DecoderLM:
 
         nslots = len(self.pattern)
 
+        serving = cache is not None
+        moe_stats = serving and cfg.moe is not None
+        slot_stack = params["slots"]
+        experts = None
+        if moe_stats:
+            # the grouped-matmul kernel takes each slot's stacked expert
+            # matrices whole and indexes the layer itself: a per-step slice
+            # would copy every expert of the layer into a temporary (a
+            # custom call's operand is a whole buffer)
+            experts = [{n: sp["moe"][n] for n in ("wi", "wg", "wo")}
+                       for sp in slot_stack]
+            slot_stack = [
+                {**sp, "moe": {n: w for n, w in sp["moe"].items()
+                               if n not in ("wi", "wg", "wo")}}
+                for sp in slot_stack]
+
         def body(carry, xs):
             x, li, aux_acc = carry
             slot_params, slot_caches = xs
-            new_caches = []
+            new_caches, stats = [], []
+            step = (li // jnp.uint32(nslots)).astype(jnp.int32)
             for s in range(nslots):
                 c = slot_caches[s] if slot_caches is not None else None
+                p = slot_params[s]
+                if experts is not None:
+                    p = {**p, "moe": {**p["moe"], **experts[s]}}
                 x, nc, aux = self._block(
-                    slot_params[s],
+                    p,
                     x,
                     slot=s,
                     positions=positions,
                     seeds=fold_layer_seeds(seeds, li),
                     cache=c,
                     cache_index=cache_index,
+                    step=step if experts is not None else None,
                 )
                 li = li + jnp.uint32(1)
                 new_caches.append(nc)
+                if moe_stats:
+                    stats.append(aux)
+                elif not serving:
+                    aux_acc = aux_acc + aux
             if slot_caches is None:
                 new_caches = None
-            return (x, li, aux_acc + aux), new_caches
+            ys = (new_caches, jnp.stack(stats) if moe_stats else None)
+            return (x, li, aux_acc), ys
 
         if remat != "none":
             policy = (
@@ -228,9 +267,10 @@ class DecoderLM:
             body = jax.checkpoint(body, policy=policy)
 
         li0 = jnp.uint32(0)
-        xs = (params["slots"], cache)
+        xs = (slot_stack, cache)
         if cfg.scan_layers:
-            (x, _, aux_total), new_cache = jax.lax.scan(body, (x, li0, 0.0), xs)
+            (x, _, aux_total), (new_cache, stats) = jax.lax.scan(
+                body, (x, li0, 0.0), xs)
         else:
             # unrolled (depth-calibration mode): same body, python loop
             carry = (x, li0, 0.0)
@@ -240,12 +280,15 @@ class DecoderLM:
                 carry, ys = body(carry, xs_i)
                 outs.append(ys)
             (x, _, aux_total) = carry
-            new_cache = (
+            new_cache, stats = (
                 jax.tree.map(lambda *a: jnp.stack(a), *outs)
-                if cache is not None
-                else None
+                if serving
+                else (None, None)
             )
         x = norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
+        if serving:
+            # (steps, slots, 2) -> flat layer order
+            aux_total = None if stats is None else stats.reshape(-1, 2)
         return x, new_cache, aux_total
 
     def logits(self, params, hidden):
@@ -286,8 +329,11 @@ class DecoderLM:
         return self.logits(params, last), new_cache
 
     def decode_step(self, params, batch, cache, cache_index, rng=None,
-                    seeds=None, logits_at=None):
-        """Advance the cache by the batch's tokens; returns (logits, cache).
+                    seeds=None, logits_at=None, with_stats=False):
+        """Advance the cache by the batch's tokens; returns (logits, cache),
+        and with ``with_stats`` also the expert layers' int32
+        ``(num_layers, 2)`` stats (see :meth:`forward`; ``None`` without
+        experts).
 
         With one token per row this is the classic decode tick.  Wider
         batches are the **prefix-extend** path (chunked prefill): token
@@ -304,12 +350,14 @@ class DecoderLM:
         never chunk shape (``tests/test_speculative.py``).
         """
         with trace_scope("repro/decode_step"):
-            hidden, new_cache, _ = self.forward(
+            hidden, new_cache, stats = self.forward(
                 params, batch, cache=cache, cache_index=cache_index, rng=rng,
                 seeds=seeds,
             )
         if logits_at is not None:
             hidden = jax.lax.dynamic_slice_in_dim(hidden, logits_at, 1, axis=1)
+        if with_stats:
+            return self.logits(params, hidden), new_cache, stats
         return self.logits(params, hidden), new_cache
 
     # ------------------------------------------------------------------
@@ -346,8 +394,10 @@ class DecoderLM:
                 k = (h @ p["attn"]["wk"]).reshape(b, s, a.num_kv_heads, a.head_dim)
                 v = (h @ p["attn"]["wv"]).reshape(b, s, a.num_kv_heads, a.head_dim)
                 if a.rope_type == "rope":
-                    q = apply_rope(q, positions, a.rope_theta)
-                    k = apply_rope(k, positions, a.rope_theta)
+                    yarn = (a.global_yarn if self._slot_window(s_idx) is None
+                            else None)
+                    q = apply_rope(q, positions, a.rope_theta, yarn)
+                    k = apply_rope(k, positions, a.rope_theta, yarn)
                 # rate coding in expectation: sigmoid-normalised projections
                 q_r = jax.nn.sigmoid(q.astype(jnp.float32))[:, 0]  # (B, H, dk)
                 k_r = jax.nn.sigmoid(k.astype(jnp.float32))[:, 0]
@@ -367,7 +417,8 @@ class DecoderLM:
                 x = x + out @ p["attn"]["wo"]
                 h2 = norm_apply(p["ln_mlp"], x, cfg.norm, cfg.norm_eps)
                 if cfg.moe:
-                    ff, _ = moe_apply(p["moe"], h2, cfg.moe, cfg.act)
+                    ff, _ = moe_apply(p["moe"], h2, cfg.moe, cfg.act,
+                                      valid=positions >= 0)
                 else:
                     ff = mlp_apply(p["mlp"], h2, cfg.act)
                 x = x + ff
@@ -425,9 +476,14 @@ class DecoderLM:
             base["tokens"] = jax.ShapeDtypeStruct((b, s), jnp.int32)
         return base
 
-    def cache_specs(self, shape: ShapeConfig) -> list:
+    def cache_specs(self, shape: ShapeConfig,
+                    page_size: Optional[int] = None) -> list:
         """Stacked KV-cache ShapeDtypeStructs per pattern slot.
 
+        Window slots hold ``min(window, S)`` rows, or, with ``page_size``
+        (a paged engine's staging row), the paged layout's ring
+        (``attention.window_ring``), so a staged row scatters into pages
+        row for row.
         ``spike_storage="packed"`` (SSA impl) swaps the real-valued k/v
         leaves for uint32 spike bit-planes — (steps, B, S, T, H_kv,
         ceil(hd/32)) — 1 bit per cached spike instead of a 16/32-bit lane
@@ -444,6 +500,8 @@ class DecoderLM:
         slots = []
         for s_idx in range(len(self.pattern)):
             w = self._slot_window(s_idx)
+            if w is not None and page_size:
+                w = window_ring(w, page_size)
             s_cache = min(w, shape.seq_len) if w is not None else shape.seq_len
             if packed:
                 plane = jax.ShapeDtypeStruct(
@@ -486,7 +544,9 @@ class DecoderLM:
         """Fresh decode cache.
 
         ``layout="slab"`` (default): one contiguous ``(B, S_cache, ...)``
-        region per batch row, per-layer extents clamped to sliding windows.
+        region per batch row, per-layer extents clamped to sliding windows
+        (to the paged ring where ``page_size`` is given; see
+        :meth:`cache_specs`).
 
         ``layout="paged"``: a shared page pool — every leaf becomes
         ``(steps, num_pages, page_size, ...)`` plus a block table ``bt:
@@ -525,7 +585,7 @@ class DecoderLM:
             return jnp.zeros(s.shape, s.dtype)
 
         if layout == "slab":
-            return jax.tree.map(init_leaf, self.cache_specs(shape))
+            return jax.tree.map(init_leaf, self.cache_specs(shape, page_size))
         if layout != "paged":
             raise ValueError(f"cache layout must be 'slab' or 'paged', got {layout!r}")
         if num_pages is None or page_size is None:

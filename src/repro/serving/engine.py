@@ -42,10 +42,15 @@ bit-identical — while peak prefill memory drops from O(prompt bucket) to
 O(chunk) and pages are claimed per chunk: admission no longer waits for a
 full-prompt page grant, and a request mid-prefill pauses/resumes at chunk
 boundaries (or is rolled back entirely when running requests need its
-pages).  Prompts longer than the smallest sliding-window extent (or than
-``max_seq``) keep the one-shot slab-staged fallback, exactly as they
-already bypass pow2 bucketing.  With ``share_prefix=True``, chunks fully
-covered by already-resident shared prefix pages are skipped outright.
+pages).  Every prompt up to ``max_seq`` chunks, sliding-window models
+included: a window layer's paged cache is a ring of the window plus one
+page (``attention.window_ring``), so a chunk's writes never evict a key its
+own queries still see, and the attention masks by absolute position (a
+``prefill_chunk`` wider than that one page plus a row is refused where a
+ring is shorter than ``max_seq``).  Only prompts beyond ``max_seq`` keep
+the one-shot slab-staged fallback.  With
+``share_prefix=True``, chunks fully covered by already-resident shared
+prefix pages are skipped outright.
 
 Cache layouts (``AttentionConfig.cache_layout``):
 
@@ -109,7 +114,10 @@ and splits the tick into named timed phases (``schedule`` /
 ``draft`` / ``verify`` on speculative engines) —
 exportable to Perfetto via :func:`repro.obs.perfetto.export_perfetto`.
 Tracing never touches device state, so a traced engine's token streams
-are bit-identical to an untraced one's.
+are bit-identical to an untraced one's.  Models with expert layers return
+per-layer stats from every decode and chunk program (routed pairs, experts
+with rows); a traced engine fetches them with the next tokens it fetches
+and records them on the ``decode_tick`` / ``prefill_chunk`` events.
 """
 from __future__ import annotations
 
@@ -347,7 +355,7 @@ def _tp_wrap_model(make, rules, kv_heads: int):
         def wrapped(*args):
             tok = use_rules(rules)
             try:
-                logits, cache = fn(*args)
+                logits, cache, *stats = fn(*args)
             finally:
                 reset_rules(tok)
             logits = jax.lax.with_sharding_constraint(
@@ -356,7 +364,8 @@ def _tp_wrap_model(make, rules, kv_heads: int):
                     rules.mesh, jax.sharding.PartitionSpec()
                 ),
             )
-            return logits, constrain_serving_cache(cache, rules, kv_heads)
+            return (logits, constrain_serving_cache(cache, rules, kv_heads),
+                    *stats)
 
         return wrapped
 
@@ -539,11 +548,17 @@ class ServingEngine:
         decode_params = inspect.signature(model.decode_step).parameters
         self._seeded = "seeds" in decode_params
         self._has_logits_at = "logits_at" in decode_params
+        # expert layers' per-layer stats: a third output of the decode and
+        # chunk programs, fetched only when traced (see _fetch)
+        has_moe = getattr(getattr(model, "cfg", None), "moe", None) is not None
+        stats_kw = ({"with_stats": True}
+                    if has_moe and "with_stats" in decode_params else {})
+        self._stats_pending: list = []
         if self._seeded:
             self._decode = self._jit_model(
                 model, "decode_seeded",
                 lambda: lambda p, batch, cache, idx, seeds: model.decode_step(
-                    p, batch, cache, idx, seeds=seeds
+                    p, batch, cache, idx, seeds=seeds, **stats_kw
                 ),
             )
         else:
@@ -641,14 +656,18 @@ class ServingEngine:
                 num_slots, max_seq, layout="paged",
                 num_pages=num_pages, page_size=ps,
             ))
-            # per-layer rolling extents (sliding windows) — the engine needs
-            # them to know which columns a decode tick writes (CoW guard)
+            # per-layer rolling extents (sliding-window rings) — the engine
+            # needs them to know which columns a decode tick writes (CoW
+            # guard)
+            from repro.attention import window_ring
+
             extents = {max_seq}
+            windows = []
             slot_window = getattr(model, "_slot_window", None)
             if callable(slot_window) and hasattr(model, "pattern"):
-                for s_idx in range(len(model.pattern)):
-                    w = model._slot_window(s_idx)
-                    extents.add(min(w, max_seq) if w is not None else max_seq)
+                windows = [w for w in map(slot_window, range(len(model.pattern)))
+                           if w is not None]
+            extents.update(min(window_ring(w, ps), max_seq) for w in windows)
             self._slot_extents = sorted(extents)
             self._preempted: list[Request] = []
             self._admit_order: dict[int, int] = {}    # uid -> admission seq
@@ -690,11 +709,23 @@ class ServingEngine:
                     model, "chunk",
                     lambda: lambda p, batch, cache, idx, seeds, last:
                         model.decode_step(
-                            p, batch, cache, idx, seeds=seeds, logits_at=last
+                            p, batch, cache, idx, seeds=seeds, logits_at=last,
+                            **stats_kw
                         ),
                 )
             self._inflight: Optional[_ChunkedPrefill] = None
             self._chunk_signatures: set[tuple[int, int]] = set()
+            # a chunk wider than a ring's spare rows would evict keys its
+            # own queries still see
+            for w in windows:
+                ring = window_ring(w, ps)
+                if ring < max_seq and self.prefill_chunk > ring - w + 1:
+                    raise ValueError(
+                        f"prefill_chunk={self.prefill_chunk} is wider than "
+                        f"the spare rows of a sliding-window ring ({ring} "
+                        f"rows for window {w}: {ring - w + 1} spare); "
+                        f"chunk by one page (page_size={ps})"
+                    )
         else:
             if num_pages is not None or page_size is not None:
                 raise ValueError(
@@ -742,11 +773,14 @@ class ServingEngine:
         # pristine single-row cache: the fill state padded prompt rows are
         # reset to after prefill (zeros / packed enc(0) / pos=-1); also the
         # template every admission prefills from (functional updates never
-        # mutate it)
-        self._init_row = self._place_cache(model.init_cache(1, max_seq))
+        # mutate it).  A paged engine's window slots take the pool's ring
+        # extent, so a staged row scatters into pages row for row
+        row_kw = {"page_size": self.pool.page_size} if self.paged else {}
+        self._init_row = self._place_cache(
+            model.init_cache(1, max_seq, **row_kw))
         # smallest per-layer cache extent along the sequence axis (leaves are
-        # (L, B, S, ...)): sliding-window layers allocate S = window, and a
-        # padded prompt longer than that would evict real rows via the
+        # (L, B, S, ...)): sliding-window layers allocate S = window (ring),
+        # and a padded prompt longer than that would evict real rows via the
         # prefill tail-keep — such prompts prefill at exact length instead
         extents = {
             leaf.shape[2]
@@ -829,7 +863,35 @@ class ServingEngine:
         if tr is not None:
             if self._event_tags:
                 data = {**self._event_tags, **data}
-            tr.emit(kind, tick=self._ticks.value, uid=uid, row=row, **data)
+            return tr.emit(kind, tick=self._ticks.value, uid=uid, row=row,
+                           **data)
+        return None
+
+    def _run_program(self, fn, args, span: str):
+        """Launch a decode / chunk program inside its dispatch span and
+        take its new cache; returns (logits, expert stats or None)."""
+        with self._span(span):
+            logits, self.cache, *stats = fn(*args)
+        return logits, (stats[0] if stats else None)
+
+    def _note_stats(self, event, stats):
+        """Hold a traced program's expert stats for its event until the
+        next token fetch (untraced engines never fetch them)."""
+        if event is not None and stats is not None:
+            self._stats_pending.append((event, stats))
+
+    def _fetch(self, tokens) -> np.ndarray:
+        """Sampled tokens to the host.  The expert stats of the programs
+        run since the last fetch ride along in the same transfer and land
+        on their events as ``moe_pairs`` / ``moe_experts`` (per layer)."""
+        if not self._stats_pending:
+            return np.asarray(tokens)
+        pending, self._stats_pending = self._stats_pending, []
+        tokens, stats = jax.device_get((tokens, [st for _, st in pending]))
+        for (event, _), st in zip(pending, stats):
+            event.data["moe_pairs"] = [int(v) for v in st[:, 0]]
+            event.data["moe_experts"] = [int(v) for v in st[:, 1]]
+        return np.asarray(tokens)
 
     def _phase(self, name: str):
         """Timed named tick phase when traced; a shared no-op otherwise."""
@@ -974,7 +1036,7 @@ class ServingEngine:
         m.observe("ttft_wall_s", now - self._submit_wall.pop(id(req), now))
         self.key, sub = jax.random.split(self.key)
         with self._span("repro/fetch_tokens"):
-            nxt = int(self.sampler(sub, logits[0, -1]))
+            nxt = int(self._fetch(self.sampler(sub, logits[0, -1])))
         req.out_tokens.append(nxt)
         m.inc("tokens_sampled")
         if len(req.out_tokens) >= req.max_new_tokens:
@@ -1010,7 +1072,7 @@ class ServingEngine:
     # prefix sharing: lookup / registration over (seed, token-prefix) keys
     # ------------------------------------------------------------------
     def _sharable(self, req: Request) -> bool:
-        """Only prompts whose prefill never wraps a sliding-window extent
+        """Only prompts whose prefill never wraps a sliding-window ring
         have page contents that are a pure function of the token prefix (a
         wrapped window slot's early rows hold *tail* tokens)."""
         return (
@@ -1183,16 +1245,13 @@ class ServingEngine:
     # chunked prefill: prefix-extend chunks written directly into pages
     # ------------------------------------------------------------------
     def _chunkable(self, req: Request) -> bool:
-        """Chunked prefill serves every prompt the pow2-bucketed one-shot
-        path serves: prompts longer than the smallest sliding-window cache
-        extent (or than ``max_seq``) would tail-keep in the slab staging
-        row — a layout chunk writes cannot reproduce incrementally — so
-        they keep the one-shot fallback."""
-        return (
-            self.paged
-            and self._chunk is not None
-            and 0 < len(req.prompt) <= self._min_seq_extent
-        )
+        """Chunked prefill serves every prompt up to ``max_seq``, across
+        sliding windows too (their rings leave a chunk's worth of spare
+        rows; ``attention.window_ring``).  The one-shot fallback is for
+        prompts beyond ``max_seq``, which tail-keep in the slab staging
+        row."""
+        p = len(req.prompt)
+        return self.paged and self._chunk is not None and 0 < p <= self.max_seq
 
     def _chunk_bucket(self, s: int) -> int:
         """Pow2-bucket a partial chunk's length (clamped to the chunk size)
@@ -1243,11 +1302,11 @@ class ServingEngine:
         )
         # the span holds the program launch alone: operand transfers and
         # scalar conversions happen before it
-        with self._span("repro/prefill_chunk_dispatch"):
-            logits, self.cache = self._chunk(*args)
+        logits, stats = self._run_program(
+            self._chunk, args, "repro/prefill_chunk_dispatch")
         self.metrics.inc("prefill_chunks_run")
-        self._trace("prefill_chunk", uid=req.uid, c0=c0, c1=c1,
-                    bucket=sb, width=width)
+        self._note_stats(self._trace("prefill_chunk", uid=req.uid, c0=c0,
+                                     c1=c1, bucket=sb, width=width), stats)
         return logits if want_logits else None
 
     def _begin_chunked(self, req: Request, slot: int):
@@ -1511,12 +1570,14 @@ class ServingEngine:
             steps = slot_d["pos"].shape[0]
             slot_d["bt"] = jnp.broadcast_to(arr[None], (steps,) + arr.shape)
 
-    def _decode_tick(self, tokens: np.ndarray):
+    def _decode_tick(self, tokens: np.ndarray, event=None):
         """One fused decode step over all rows for the given next tokens.
 
         Every host array crosses the dispatch boundary through ``_dev``
         (copies): dispatch is async and the scheduler mutates slot_pos /
         slot_seeds / tables right after dispatch on replay ticks.
+        ``event``: the tick's traced ``decode_tick`` event, which receives
+        the program's expert stats.
         """
         positions = self.slot_pos[:, None].astype(np.int32)
         batch = {
@@ -1527,8 +1588,9 @@ class ServingEngine:
                 _dev(self.slot_pos))                 # per-row write offsets
         if self._seeded:
             args += (_dev(self.slot_seeds),)
-        with self._span("repro/decode_dispatch"):
-            logits, self.cache = self._decode(*args)
+        logits, stats = self._run_program(
+            self._decode, args, "repro/decode_dispatch")
+        self._note_stats(event, stats)
         return logits
 
     def _replay(self, slot: int, req: Request):
@@ -2072,8 +2134,8 @@ class ServingEngine:
             {"tokens": _dev(tokens), "positions": _dev(positions)},
             self.cache, _dev(idx), _dev(self.slot_seeds),
         )
-        with self._span("repro/verify_dispatch"):
-            logits, self.cache = self._decode(*args)
+        logits, _ = self._run_program(self._decode, args,
+                                      "repro/verify_dispatch")
         self.metrics.inc("verify_dispatches")
         return logits
 
@@ -2315,6 +2377,7 @@ class ServingEngine:
             tokens = np.zeros((self.b, 1), np.int32)
             for slot, req in self.active.items():
                 tokens[slot, 0] = req.out_tokens[-1]
+        event = None
         if self.tracer is not None:
             data = {
                 "active": len(self.active),
@@ -2324,11 +2387,11 @@ class ServingEngine:
                 data["pages_used"] = self.pool.num_used
                 if self._cache_on:
                     data["cache_pages"] = self.pool.num_cached
-            self._trace("decode_tick", **data)
+            event = self._trace("decode_tick", **data)
         # NOTE: static-shape engine uses one shared cache_index per tick via
         # per-slot positions; the cache write offset is each slot's position
         with self._phase("dispatch"):
-            logits = self._decode_tick(tokens)
+            logits = self._decode_tick(tokens, event)
         tr = self.tracer
         if tr is not None and tr.sync_device:
             # separates async-dispatch cost from device execution in the
@@ -2339,7 +2402,7 @@ class ServingEngine:
             self._ticks.inc()
             self.key, sub = jax.random.split(self.key)
             with self._span("repro/fetch_tokens"):
-                nxt = np.asarray(self.sampler(sub, logits[:, -1]))
+                nxt = self._fetch(self.sampler(sub, logits[:, -1]))
             finished = self._commit(nxt)
         return finished0 + finished
 

@@ -5,7 +5,9 @@ Runs in its own interpreter so the parent pytest process can force an
 backend.  Asserts that tensor-parallel (2- and 4-shard) and 2-replica
 engines reproduce the committed golden token streams bit-for-bit; with
 ``--vs-unsharded``, that 2- and 4-shard engines reproduce the unsharded
-engine's streams, for the XLA and the fused (Pallas) backends.
+engine's streams, for the XLA and the fused (Pallas) backends; with
+``--moe``, the same for an expert model, whose dropless grouped-matmul
+kernel runs on replicated weights on every shard.
 """
 import dataclasses
 import json
@@ -25,6 +27,10 @@ CASES = [
     ("codeqwen-ssa-packed-paged", "codeqwen15_7b", "packed", "paged"),
     ("codeqwen-ssa-dense-slab", "codeqwen15_7b", "dense", "slab"),
     ("gemma2-ssa-packed-paged", "gemma2_9b", "packed", "paged"),
+]
+# expert models: no golden fixture, compared with the unsharded engine only
+MOE_CASES = [
+    ("mellum2-ssa-packed-paged", "mellum2_12b_a2_5b", "packed", "paged"),
 ]
 
 
@@ -57,10 +63,10 @@ def build(arch, storage, layout, backend="auto"):
     return model, model.init(jax.random.PRNGKey(0)), kw
 
 
-def vs_unsharded():
+def vs_unsharded(cases=CASES):
     """TP engines against the unsharded engine in this same process, for
     the XLA backends and the Pallas kernels (run per head shard)."""
-    for name, arch, storage, layout in CASES:
+    for name, arch, storage, layout in cases:
         # the windowed gemma2 case adds no kernel path: XLA only there
         backends = ("xla",) if arch == "gemma2_9b" else ("xla", "fused")
         for backend in backends:
@@ -80,6 +86,8 @@ def main():
     )
     if sys.argv[1:] == ["--vs-unsharded"]:
         return vs_unsharded()
+    if sys.argv[1:] == ["--moe"]:
+        return vs_unsharded(MOE_CASES)
     for name, arch, storage, layout in CASES:
         with open(GOLDEN / f"{name}.json") as f:
             want = json.load(f)["streams"]

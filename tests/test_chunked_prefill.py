@@ -105,6 +105,21 @@ def test_paged_engine_chunks_by_default_and_zero_disables():
     assert s_chunk == s_off
 
 
+def test_chunk_wider_than_window_ring_spare_rows_is_refused():
+    """A window layer's ring leaves one page (+1) of spare rows (smoke
+    gemma2: window 16, page 8 -> ring 24, 9 spare), so a two-page chunk
+    would evict keys its own queries still see: the engine refuses it
+    instead of switching such prompts to another path.  Where the ring
+    holds all of ``max_seq`` nothing wraps, and any chunk is accepted."""
+    _, model, params = _model_and_params("gemma2_9b", "ssa", "packed", "paged")
+    with pytest.raises(ValueError, match="spare rows"):
+        ServingEngine(model, params, num_slots=2, max_seq=32, page_size=8,
+                      prefill_chunk=16)
+    eng = ServingEngine(model, params, num_slots=2, max_seq=24, page_size=8,
+                        prefill_chunk=16)
+    assert eng.prefill_chunk == 16
+
+
 # ---------------------------------------------------------------------------
 # chunk-boundary edge cases: bit-identity with the unchunked engine
 # ---------------------------------------------------------------------------
@@ -148,18 +163,25 @@ def test_chunk_smaller_than_sliding_window_matches_gemma2():
 
 
 def test_overlong_and_overwindow_prompts_fall_back_to_one_shot():
-    """Prompts longer than the smallest sliding-window extent tail-keep in
-    the slab staging row — a layout chunk writes cannot reproduce — so they
-    keep the one-shot path (and still match the slab engine)."""
+    """Only prompts longer than ``max_seq`` tail-keep in the slab staging
+    row — a layout chunk writes cannot reproduce — so only they keep the
+    one-shot path.  A prompt longer than the sliding window (17 > smoke
+    gemma2 window 16) now chunks across it: the window layers' ring holds
+    the window plus one page, and matches the slab engine; the overlong
+    prompt streams what the one-shot paged engine streams."""
     prompts = _prompts(256, [17, 5], seed=4)  # 17 > smoke gemma2 window 16
-    kw = dict(arch="gemma2_9b", storage="packed", prompts=prompts, slots=2,
-              max_seq=32, page_size=8)
+    kw = dict(arch="gemma2_9b", storage="packed", slots=2, max_seq=32,
+              page_size=8)
     s_slab, _ = _serve(arch="gemma2_9b", storage="packed", layout="slab",
                        prompts=prompts, slots=2, max_seq=32)
-    s_chunk, eng = _serve(**kw)
+    s_chunk, eng = _serve(prompts=prompts, **kw)
     assert s_chunk == s_slab
-    st = eng.stats()
-    assert st["chunked_prefills"] == 1  # only the short prompt chunked
+    assert eng.stats()["chunked_prefills"] == 2
+    overlong = _prompts(256, [37], seed=4)  # 37 > max_seq 32
+    s_long, eng = _serve(prompts=overlong, **kw)
+    s_one_shot, _ = _serve(prompts=overlong, prefill_chunk=0, **kw)
+    assert s_long == s_one_shot
+    assert eng.stats()["chunked_prefills"] == 0
 
 
 # ---------------------------------------------------------------------------

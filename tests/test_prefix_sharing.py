@@ -134,10 +134,11 @@ def test_window_wrap_copies_shared_page_and_stays_bit_identical():
     cfg = _cfg("gemma2_9b")
     prompts = _shared_prompts(cfg.vocab_size, 2, prefix_len=8, suffix_len=3,
                               seed=4)
-    # window=16 in the smoke config: 11-token prompts + 10 generated
-    # tokens cross it, wrapping writes into page 0 (the shared one)
-    s_plain, _, _ = _serve(cfg, prompts, share=False, slots=2, max_new=10)
-    s_shared, eng, _ = _serve(cfg, prompts, share=True, slots=2, max_new=10)
+    # window=16 in the smoke config, held in a ring of 24 rows (the window
+    # plus one 8-row page): 11-token prompts + 16 generated tokens cross
+    # it, wrapping writes into page 0 (the shared one)
+    s_plain, _, _ = _serve(cfg, prompts, share=False, slots=2, max_new=16)
+    s_shared, eng, _ = _serve(cfg, prompts, share=True, slots=2, max_new=16)
     assert s_shared == s_plain
     st = eng.stats()
     assert st["shared_page_hits"] >= 1

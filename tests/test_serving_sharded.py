@@ -379,3 +379,12 @@ def test_sharded_matches_unsharded_subprocess():
     """2- and 4-shard engines give the unsharded engine's streams, with the
     Pallas kernels (interpret mode here) run per head shard."""
     _run_sharded_probe("--vs-unsharded")
+
+
+@pytest.mark.slow
+def test_sharded_moe_matches_unsharded_subprocess():
+    """An expert model (Mellum smoke: dropless top-k experts, window and
+    full layers) under 2- and 4-shard serving gives the unsharded
+    engine's streams: serving TP replicates the expert weights, so the
+    grouped-matmul kernel runs whole on every shard."""
+    _run_sharded_probe("--moe")

@@ -130,3 +130,90 @@ def test_popcount_matmul_compiles(one_chip, m, n, w):
         ((m, w), jnp.uint32),
         ((n, w), jnp.uint32),
     )
+
+
+@pytest.mark.parametrize("n_q", [1, 16], ids=["decode", "chunk"])
+def test_packed_window_ring_compiles(one_chip, n_q):
+    # mellum2 window layers: 8 requests x 32 query heads over 4 KV heads
+    # against a 1040-row ring (window 1024 + one 16-row page)
+    from repro.attention import window_ring
+
+    b, h, h_kv, ring = 8, 32, 4, window_ring(1024, 16)
+
+    def fn(q, k, v, s, q_pos, kv_pos):
+        return ssa_attention(q, k, v, s, causal=True, window=1024, packed=True,
+                             q_positions=q_pos, kv_positions=kv_pos, d_k=HD)
+
+    _compile(
+        fn,
+        one_chip,
+        ((b * h, n_q, WORDS), jnp.uint32),
+        ((b * h_kv, ring, WORDS), jnp.uint32),
+        ((b * h_kv, ring, WORDS), jnp.uint32),
+        ((b * h,), jnp.uint32),
+        ((b * h, n_q), jnp.int32),
+        ((b * h_kv, ring), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("pairs", [64, 128], ids=["decode", "chunk"])
+def test_moe_gmm_compiles(one_chip, pairs):
+    # mellum2 expert layer: 64 experts of width 896 over d_model 2304; a
+    # decode tick routes 8 rows x top-8 pairs, a 16-token chunk 16 x 8
+    from repro.kernels.moe_gmm import moe_gmm
+
+    d, f, e = 2304, 896, 64
+
+    def fn(x, wg, wo, sizes):
+        return moe_gmm(moe_gmm(x, wg, sizes), wo, sizes)
+
+    text = _compile(
+        fn,
+        one_chip,
+        ((pairs, d), jnp.bfloat16),
+        ((e, d, f), jnp.bfloat16),
+        ((e, f, d), jnp.bfloat16),
+        ((e,), jnp.int32),
+    )
+    assert "moe_gmm" in text
+
+
+def test_moe_experts_compile_under_serving_tp(one_chip, monkeypatch):
+    # mellum2 expert layer at a decode tick under four-shard serving tensor
+    # parallelism: Mosaic kernels cannot be partitioned by XLA, so the
+    # grouped FFN has to run inside shard_map, whole on every shard
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import repro.attention
+    from repro.configs.base import MoEConfig
+    from repro.distributed.sharding import ServingTPRules, reset_rules, use_rules
+    from repro.models.blocks import moe_dropless
+
+    monkeypatch.setattr(repro.attention, "default_interpret", lambda: False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    rules = ServingTPRules(mesh)
+    d, f, e = 2304, 896, 64
+    moe = MoEConfig(e, 8, f)
+
+    def fn(x, router, wi, wg, wo, valid):
+        tok = use_rules(rules)
+        try:
+            p = {"router": router, "wi": wi, "wg": wg, "wo": wo}
+            return moe_dropless(p, x, moe, valid)[0]
+        finally:
+            reset_rules(tok)
+
+    text = _compile(
+        fn,
+        NamedSharding(mesh, P()),
+        ((8, 1, d), jnp.bfloat16),
+        ((d, e), jnp.float32),
+        ((e, d, f), jnp.bfloat16),
+        ((e, d, f), jnp.bfloat16),
+        ((e, f, d), jnp.bfloat16),
+        ((8, 1), jnp.bool_),
+    )
+    assert "moe_gmm" in text
